@@ -17,6 +17,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 from benchmarks import (calibrate_bench, faults_bench, kernels_bench,
                         obs_bench, paper_tables, partitioning_bench,
                         replicated_bench, sharded_bench, streaming_bench,
@@ -61,6 +63,7 @@ def main() -> None:
     wanted = ([s.strip() for s in args.only.split(",") if s.strip()]
               if args.only else None)
 
+    enable_compile_cache()
     rows = []
     failures = 0
     for bench in BENCHES:

@@ -1,7 +1,9 @@
 """Scenario-sharded sweep benchmark: the million-scenario planning path.
 
 Measures `sweep_analytical`/`sweep_simulated` with a 1-D ("scenario",)
-mesh from `repro.launch.mesh.make_sweep_mesh` over 8 XLA devices:
+mesh from `repro.launch.mesh.make_sweep_mesh` over every device this
+process sees (one chip, the four chips of a host, or the one CPU device),
+and records how many in ``n_devices``:
 
 * analytical — a 1,000,000-scenario (L,P,C,D,H,R) grid evaluated as one
   shard_map program (the SNIPPETS.md 38M-qps global planning exercise
@@ -9,68 +11,33 @@ mesh from `repro.launch.mesh.make_sweep_mesh` over 8 XLA devices:
 * simulated — a replicated fused-engine grid streamed with each device
   owning a scenario shard.
 
-The device count must be fixed BEFORE jax initializes, so the harness
-entry (`bench_sharded_sweep`) re-runs this module as a CHILD process
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` and reads
-the record it writes.  On a single-core CI host the 8 virtual devices
-timeshare one core — the numbers pin the *sharded program's* throughput
-trajectory (vs its own committed baseline on the same runner class),
-they do not claim an 8x speedup.  Results go to ``BENCH_sharded.json``
-for the bench-regression gate (``queries_per_s`` and ``scenarios_per_s``
-are both gated "higher").
+It runs in the harness's own process: a chip belongs to one process, so
+a child could not reach it.  The sharded path on virtual CPU devices is
+rehearsed by ``tests/test_sharding.py``.  Results go to
+``BENCH_sharded.json`` for the bench-regression gate (``queries_per_s``
+and ``scenarios_per_s`` are both gated "higher").
 """
 
 from __future__ import annotations
 
 import json
-import os
 import statistics
-import subprocess
-import sys
 import time
 
 from benchmarks import _util
 
-_DEVICES = 8
 _TIMING_PASSES = 3
 
 
 def bench_sharded_sweep(rows):
-    out = _util.bench_output_path("BENCH_sharded.json")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count="
-                        f"{_DEVICES}").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"), root,
-                    env.get("PYTHONPATH", "")) if p)
-    r = subprocess.run([sys.executable, "-m", "benchmarks.sharded_bench"],
-                       env=env, capture_output=True, text=True,
-                       timeout=1800)
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"sharded bench child failed\nSTDOUT:\n{r.stdout}\n"
-            f"STDERR:\n{r.stderr}")
-    record = json.loads(out.read_text())
-    rows.append((
-        "sharded_sweep", record["wall_seconds"] * 1e6,
-        f"{record['n_scenarios_analytical']} analytic scenarios on "
-        f"{_DEVICES} devices, {record['scenarios_per_s'] / 1e6:.2f}M "
-        f"scen/s; simulated {record['n_scenarios_simulated']} scen x "
-        f"{record['n_queries']} q sharded: "
-        f"{record['queries_per_s'] / 1e6:.2f}M queries/s; -> {out}"))
-
-
-def _main() -> None:
     import jax
     import jax.numpy as jnp
 
     from repro.core import capacity, sweep
     from repro.launch.mesh import make_sweep_mesh
 
-    assert len(jax.devices()) == _DEVICES, jax.devices()
     mesh = make_sweep_mesh()
+    n_dev = int(mesh.devices.size)
 
     # --- analytical: 100 x 4 x 5 x 5 x 20 x 5 = 1,000,000 scenarios ----
     big = sweep.SweepGrid.build(
@@ -98,7 +65,7 @@ def _main() -> None:
         times.append(time.perf_counter() - t0)
     dt_ana = statistics.median(times)
 
-    # --- simulated: 32-scenario replicated slab, sharded 4 per device --
+    # --- simulated: 32-scenario replicated slab, sharded over the mesh --
     sim_grid = sweep.SweepGrid.build(
         lam=jnp.linspace(30.0, 90.0, 16),
         p=jnp.asarray([8.0]),
@@ -138,11 +105,11 @@ def _main() -> None:
 
     profile = _util.profile_block(
         jax.jit(_surfaces),
-        name=f"sharded_analytical[{n_ana}x{_DEVICES}dev]", n_runs=0)
+        name=f"sharded_analytical[{n_ana}x{n_dev}dev]", n_runs=0)
 
     record = {
         "bench": "sharded_sweep",
-        "n_devices": _DEVICES,
+        "n_devices": n_dev,
         "n_scenarios_analytical": n_ana,
         "wall_seconds_analytical": dt_ana,
         "scenarios_per_s": n_ana / dt_ana,
@@ -157,7 +124,9 @@ def _main() -> None:
     }
     out = _util.bench_output_path("BENCH_sharded.json")
     out.write_text(json.dumps(record, indent=2) + "\n")
-
-
-if __name__ == "__main__":
-    _main()
+    rows.append((
+        "sharded_sweep", dt_sim * 1e6,
+        f"{n_ana} analytic scenarios on {n_dev} devices, "
+        f"{n_ana / dt_ana / 1e6:.2f}M scen/s; simulated {n_sim} scen x "
+        f"{n_q} q sharded: {n_sim * n_q / dt_sim / 1e6:.2f}M queries/s; "
+        f"-> {out}"))

@@ -1,26 +1,20 @@
-"""JAX version-compatibility shims.
+"""The JAX symbols this repo reaches through one file.
 
-The repo tracks two JAX API renames that landed at different versions:
+The installed JAX (0.9.0, pinned in ``pyproject.toml``) exposes:
 
-  * ``pallas.tpu.TPUCompilerParams`` -> ``pallas.tpu.CompilerParams``
-    (the TPU- prefix was dropped once params moved under the tpu module);
-  * mesh axis types: ``jax.sharding.AxisType`` (new enum, accepted by
-    ``jax.make_mesh(axis_types=...)``) vs older releases where
-    ``make_mesh`` has no ``axis_types`` parameter at all;
-  * ``jax.shard_map(..., check_vma=...)`` vs the older
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
+  * ``pallas.tpu.CompilerParams`` for Pallas TPU compiler options;
+  * ``jax.sharding.AxisType``, accepted by ``jax.make_mesh(axis_types=...)``;
+  * ``jax.shard_map(..., check_vma=...)``.
 
 Convention (recorded in ROADMAP.md): NO module outside this file touches a
-JAX symbol that has been renamed or gated across the versions we support.
-Kernels call :func:`tpu_compiler_params`, mesh builders call
-:func:`make_mesh` / :func:`mesh_axis_types`, and a future JAX upgrade means
-editing this one file instead of five kernels and every test body.
+JAX symbol that has been renamed or gated across JAX versions.  Kernels
+call :func:`tpu_compiler_params`, mesh builders call :func:`make_mesh` /
+:func:`mesh_axis_types`, and a future JAX upgrade means editing this one
+file instead of five kernels and every test body.
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Any, Optional, Sequence
 
 import jax
@@ -29,80 +23,30 @@ __all__ = ["tpu_compiler_params", "mesh_axis_types", "make_mesh",
            "shard_map"]
 
 
-@functools.cache
-def _compiler_params_cls():
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover - unsupported JAX
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; JAX version unsupported")
-    return cls
-
-
 def tpu_compiler_params(
     *, dimension_semantics: Optional[Sequence[str]] = None, **kwargs: Any
 ):
-    """Build Pallas TPU compiler params under either API name.
+    """Pallas TPU compiler params; an option this JAX lacks raises."""
+    from jax.experimental.pallas import tpu as pltpu
 
-    Unknown keyword arguments are dropped (with the field filter below)
-    rather than exploded, so kernels can request newer tuning knobs and
-    still compile on older JAX.
-    """
-    cls = _compiler_params_cls()
-    accepted = set(inspect.signature(cls).parameters)
-    full = dict(kwargs, dimension_semantics=dimension_semantics)
-    return cls(**{k: v for k, v in full.items()
-                  if k in accepted and v is not None})
-
-
-@functools.cache
-def _axis_type_auto():
-    """The 'Auto' mesh axis type, or None when this JAX has no such enum."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return axis_type.Auto
-    return None
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                **kwargs)
 
 
 def mesh_axis_types(n_axes: int):
-    """``axis_types`` tuple for an all-Auto mesh, or None if unsupported.
-
-    Auto is the default partitioning mode everywhere we build meshes, so
-    degrading to "no axis_types argument" on older JAX is behavior-neutral.
-    """
-    auto = _axis_type_auto()
-    if auto is None:
-        return None
-    return (auto,) * n_axes
+    """``axis_types`` tuple for an all-Auto mesh."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None):
-    """``jax.make_mesh`` with all-Auto axis types where supported."""
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    sig = inspect.signature(jax.make_mesh)
-    types = mesh_axis_types(len(axis_names))
-    if types is not None and "axis_types" in sig.parameters:
-        kwargs["axis_types"] = types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with all-Auto axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=mesh_axis_types(len(axis_names)),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Per-shard mapping under either the top-level or experimental API.
-
-    ``check_vma`` (varying-manual-axes checking) is the new name of the
-    old ``check_rep`` replication check; both toggle the same validation.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    kw = ("check_vma" if "check_vma" in inspect.signature(fn).parameters
-          else "check_rep")
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kw: check_vma})
+    """Per-shard mapping (``check_vma`` toggles varying-axes checking)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

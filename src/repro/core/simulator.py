@@ -181,8 +181,8 @@ def fcfs_completion_times(arrivals: Array, services: Array,
 
     arrivals: (..., n) nondecreasing along the last axis.
     services: (..., n) positive.
-    impl: "xla" (associative_scan) or "pallas" (TPU kernel; interpret=True
-    on CPU) — both compute the identical recurrence.  The default
+    impl: "xla" (associative_scan) or "pallas" (TPU kernel; interpreted
+    only on the CPU test backend) — both compute the identical recurrence.  The default
     "auto" picks "pallas" on real TPU hardware and "xla" everywhere
     else (interpret-mode Pallas is slower than associative_scan); see
     `repro.kernels.maxplus_scan.ops.resolve_scan_impl`.
@@ -622,7 +622,7 @@ def _fcfs_segmented(arrivals: Array, services: Array, flags: Array,
     completion time of that queue's prior work), pre-composed at segment
     heads: seeding the head and resetting there is exactly seeding the
     whole segment.  ``impl`` picks `jax.lax.associative_scan` ("xla") or
-    the Pallas segmented kernel ("pallas"; interpret mode off-TPU).
+    the Pallas segmented kernel ("pallas"; see `ops.interpret_mode`).
     """
     a = arrivals + services
     b = services

@@ -439,6 +439,10 @@ def _sharded_batch(run, mesh, key, proc: ArrivalProcess,
     unsharded ones.  Every `SimResult` leaf leads with the scenario
     axis, so a single spec works as the out-spec pytree prefix; padded
     scenarios are sliced off before returning.
+
+    The mapped function runs under ``jax.jit``: a leaf with no elements
+    (``tap_response`` at ``tap_size=0``) comes back from XLA replicated,
+    which eager ``shard_map`` rejects against its scenario out-spec.
     """
     axis, n_dev = _check_sweep_mesh(mesh)
     n_slab = proc.rates.shape[0]
@@ -456,9 +460,9 @@ def _sharded_batch(run, mesh, key, proc: ArrivalProcess,
         proc_d = ArrivalProcess.piecewise(rates_d, bin_seconds)
         return run(keys_d[0], proc_d, params_d)
 
-    res = compat.shard_map(
+    res = jax.jit(compat.shard_map(
         shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
-        out_specs=spec, check_vma=False)(keys, rates, params)
+        out_specs=spec, check_vma=False))(keys, rates, params)
     return jax.tree_util.tree_map(lambda x: x[:n_slab], res)
 
 

@@ -1,7 +1,9 @@
 """Jitted public wrapper for the max-plus scan Pallas kernel.
 
-Handles arbitrary leading shapes, pads the scan axis with the semiring
-identity (a = -inf, b = 0), and picks interpret mode automatically off-TPU.
+Handles arbitrary leading shapes and pads the scan axis with the
+semiring identity (a = -inf, b = 0).  The kernel runs compiled on a TPU;
+only the CPU backend, where the tests run, interprets it
+(:func:`interpret_mode`).
 """
 
 from __future__ import annotations
@@ -25,8 +27,22 @@ _logger = logging.getLogger(__name__)
 _logged_auto = False
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted on this backend.
+
+    The one place that decides: compiled on a TPU, interpreted on the
+    CPU backend that the tests run on, and an error anywhere else, so a
+    kernel never runs interpreted where the caller expected the chip.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the maxplus Pallas kernel runs compiled on a TPU or interpreted "
+        f"on the CPU test backend; backend {backend!r} is neither "
+        "(use impl='xla')")
 
 
 def resolve_scan_impl(impl: str = "auto") -> str:
@@ -35,13 +51,16 @@ def resolve_scan_impl(impl: str = "auto") -> str:
     Interpret-mode Pallas is strictly slower than
     ``jax.lax.associative_scan`` off-TPU, so "auto" (now the default of
     the simulator entry points) only picks the kernel on real TPU
-    hardware.  Pass "xla" or "pallas" explicitly to override.  Logs the
-    auto choice once per process.
+    hardware.  Pass "xla" or "pallas" explicitly to override; "pallas"
+    raises on a backend that is neither a TPU nor the CPU test backend.
+    Logs the auto choice once per process.
     """
     global _logged_auto
     if impl not in SCAN_IMPLS:
         raise ValueError(f"unknown scan impl {impl!r}; choose one of "
                          f"{SCAN_IMPLS}")
+    if impl == "pallas":
+        interpret_mode()  # raises on a backend that may not run it
     if impl != "auto":
         return impl
     resolved = "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -52,19 +71,21 @@ def resolve_scan_impl(impl: str = "auto") -> str:
     return resolved
 
 
-@functools.partial(jax.jit, static_argnames=("block_len", "row_tile",
-                                             "interpret"))
 def maxplus_scan(
     a: jax.Array,
     b: jax.Array,
     *,
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
-    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Inclusive (max, +) scan along the last axis; any leading shape."""
-    if interpret is None:
-        interpret = _auto_interpret()
+    return _maxplus_scan(a, b, block_len=block_len, row_tile=row_tile,
+                         interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("block_len", "row_tile",
+                                             "interpret"))
+def _maxplus_scan(a, b, *, block_len: int, row_tile: int, interpret: bool):
     orig_shape = a.shape
     n = orig_shape[-1]
     rows = 1
@@ -87,8 +108,6 @@ def maxplus_scan(
     return out_a, out_b
 
 
-@functools.partial(jax.jit, static_argnames=("block_len", "row_tile",
-                                             "interpret"))
 def maxplus_segment_scan(
     a: jax.Array,
     b: jax.Array,
@@ -96,7 +115,6 @@ def maxplus_segment_scan(
     *,
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
-    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Segmented inclusive (max, +) scan along the last axis.
 
@@ -107,8 +125,15 @@ def maxplus_segment_scan(
     single kernel pass.  Any leading shape; padding uses the semiring
     identity (a = -inf, b = 0, f = 0), which cannot disturb real lanes.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    return _maxplus_segment_scan(a, b, f, block_len=block_len,
+                                 row_tile=row_tile,
+                                 interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("block_len", "row_tile",
+                                             "interpret"))
+def _maxplus_segment_scan(a, b, f, *, block_len: int, row_tile: int,
+                          interpret: bool):
     orig_shape = a.shape
     n = orig_shape[-1]
     rows = 1
@@ -142,7 +167,6 @@ def maxplus_scan_seeded(
     *,
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
-    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Inclusive (max, +) scan seeded by the carry of everything earlier.
 
@@ -158,7 +182,7 @@ def maxplus_scan_seeded(
     ``carry_a``/``carry_b`` broadcast against ``a.shape[:-1]``.
     """
     out_a, out_b = maxplus_scan(a, b, block_len=block_len,
-                                row_tile=row_tile, interpret=interpret)
+                                row_tile=row_tile)
     carry_a = jnp.asarray(carry_a)
     if carry_b is None:
         carry_b = jnp.zeros_like(carry_a)

@@ -2,6 +2,7 @@
 
 import jax
 import numpy as np
+import pytest
 
 from repro import compat
 
@@ -12,16 +13,16 @@ def test_tpu_compiler_params_builds():
     assert cp.dimension_semantics == ("parallel", "arbitrary")
 
 
-def test_tpu_compiler_params_drops_unknown_kwargs():
-    cp = compat.tpu_compiler_params(
-        dimension_semantics=("parallel",),
-        some_future_knob_that_does_not_exist=123)
-    assert cp.dimension_semantics == ("parallel",)
+def test_tpu_compiler_params_rejects_unknown_kwargs():
+    with pytest.raises(TypeError, match="some_future_knob"):
+        compat.tpu_compiler_params(
+            dimension_semantics=("parallel",),
+            some_future_knob_that_does_not_exist=123)
 
 
 def test_mesh_axis_types_shape_or_none():
     types = compat.mesh_axis_types(3)
-    assert types is None or len(types) == 3
+    assert types == (jax.sharding.AxisType.Auto,) * 3
 
 
 def test_make_mesh_single_device():

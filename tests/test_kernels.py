@@ -31,6 +31,22 @@ def test_maxplus_scan_sweep(shape, blk):
     np.testing.assert_allclose(np.asarray(ob), np.asarray(rb), rtol=1e-5)
 
 
+@pytest.mark.parametrize("backend,interpret", [
+    ("tpu", False), ("cpu", True), ("gpu", None)])
+def test_maxplus_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Compiled on a TPU, interpreted on the CPU, refused elsewhere."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            mp_ops.interpret_mode()
+        with pytest.raises(RuntimeError, match="neither"):
+            mp_ops.resolve_scan_impl("pallas")
+        assert mp_ops.resolve_scan_impl("auto") == "xla"
+    else:
+        assert mp_ops.interpret_mode() is interpret
+        assert mp_ops.resolve_scan_impl("pallas") == "pallas"
+
+
 def test_maxplus_ref_equals_sequential():
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
     a = jax.random.normal(k1, (3, 257))
