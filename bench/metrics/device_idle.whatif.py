@@ -1,0 +1,11 @@
+"""device_idle.whatif: the share of the traced window, in percent, in which
+the cell's chips ran no operation (1 - busy / window, averaged over the
+chips), in a what-if cell."""
+
+import trace_reduce
+
+
+def read(w):
+    if w.kind != "whatif" or w.trace is None:
+        return None
+    return trace_reduce.idle_percent(w.trace_devices(), w.trace["window"])

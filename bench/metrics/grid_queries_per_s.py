@@ -1,0 +1,9 @@
+"""grid_queries_per_s: simulated queries of every planning-grid call in the
+window (scenarios x queries per call), over the window's wall time, first
+call's start to last call's end."""
+
+
+def read(w):
+    if w.kind != "grid":
+        return None
+    return w.n_calls * w.work / w.window_s

@@ -1,0 +1,9 @@
+"""The benchmark's CPU tests: its modules and the planner on the path."""
+
+import pathlib
+import sys
+
+BENCH = pathlib.Path(__file__).resolve().parents[1]
+for path in (BENCH.parent / "src", BENCH):
+    if str(path) not in sys.path:
+        sys.path.insert(0, str(path))
