@@ -21,6 +21,7 @@ from repro.core.cluster import ClusterSpec, resolve_cluster
 from repro.core.faults import FaultSpec
 from repro.core.queueing import ServerParams
 from repro.launch.elastic import AutoscalePolicy
+from repro.obs.spans import count, span
 
 Array = jax.Array
 
@@ -131,6 +132,7 @@ def max_rate_under_slo(
     hi = lam_max
 
     def body(state, _):
+        count("plan/size_traced")    # runs when the bisection is traced
         lo, hi = state
         mid = 0.5 * (lo + hi)
         ok = response(mid) <= slo_seconds
@@ -279,35 +281,51 @@ def plan_capacity(
             "survive_faults synthesizes its own k-replicas-down "
             "FaultSpec; a ClusterSpec.fault would double-inject — give "
             "one or the other")
+    with span("plan"):
+        return _plan(params, target_rate, slo_seconds, spec, simulate,
+                     key, n_queries, mode, k_down)
+
+
+def _plan(params, target_rate, slo_seconds, spec, simulate, key,
+          n_queries, mode, k_down) -> CapacityPlan:
+    """`plan_capacity` after its arguments are checked: the sizing, then
+    the simulated cross-check, each under its own span."""
     cache = spec.result_cache
-    n, per_replica = replicas_needed(
-        params, target_rate, slo_seconds, result_cache=cache)
-    # N+k: the bound must hold at the SURVIVOR rate target / n_base, so
-    # provisioning gains k spares on top of the fault-free answer
-    n_i = int(n) + k_down
-    rate = float(target_rate) / max(int(n), 1)
-    lo, hi = queueing.response_time_bounds(rate, params)
-    if cache is not None:
-        hi = queueing.response_time_with_result_cache(
-            rate, params, *cache)
-    p = int(jnp.asarray(params.p))
-    util = queueing.utilization(rate, queueing.service_time_server(params))
+    with span("plan.size"):
+        n, per_replica = replicas_needed(
+            params, target_rate, slo_seconds, result_cache=cache)
+        # N+k: the bound must hold at the SURVIVOR rate target / n_base,
+        # so provisioning gains k spares on top of the fault-free answer
+        n_i = int(n) + k_down
+        rate = float(target_rate) / max(int(n), 1)
+        lo, hi = queueing.response_time_bounds(rate, params)
+        if cache is not None:
+            hi = queueing.response_time_with_result_cache(
+                rate, params, *cache)
+        p = int(jnp.asarray(params.p))
+        util = queueing.utilization(
+            rate, queueing.service_time_server(params))
+        upper_ms, lower_ms = float(hi) * 1e3, float(lo) * 1e3
+        util = float(util)
+        per_replica = float(per_replica)
     sim_ms = sim_p95_ms = mean_active = faulted_p95_ms = None
     _SIM_REPLICA_CAP = 256
     sim_r = (spec.autoscale.max_r if spec.autoscale is not None else n_i)
-    feasible = float(per_replica) > 1e-9 or spec.autoscale is not None
+    feasible = per_replica > 1e-9 or spec.autoscale is not None
     if simulate and feasible and sim_r <= _SIM_REPLICA_CAP:
         from repro.core import simulator  # deferred: planner-only dep
         key = jax.random.PRNGKey(0) if key is None else key
         sim_spec = (spec if spec.autoscale is not None
                     else dataclasses.replace(spec, r=n_i))
-        sim = simulator.simulate_fork_join(
-            key, float(target_rate), n_queries, params, mode=mode,
-            cluster=sim_spec)
-        sim_ms = float(sim.mean_response) * 1e3
-        sim_p95_ms = float(sim.quantile(0.95)) * 1e3
-        if spec.autoscale is not None:
-            mean_active = float(sim.mean_active_replicas)
+        with span("plan.simulate"):
+            sim = simulator.simulate_fork_join(
+                key, float(target_rate), n_queries, params, mode=mode,
+                cluster=sim_spec)
+        with span("plan.read"):
+            sim_ms = float(sim.mean_response) * 1e3
+            sim_p95_ms = float(sim.quantile(0.95)) * 1e3
+            if spec.autoscale is not None:
+                mean_active = float(sim.mean_active_replicas)
         if k_down:
             # the survivability check proper: k replicas held down for
             # the WHOLE run (the peak-coincident worst case), failover
@@ -317,34 +335,35 @@ def plan_capacity(
             horizon = 2.0 * n_queries / max(float(target_rate), 1e-9)
             down = FaultSpec(
                 outages=tuple((j, 0.0, horizon) for j in range(k_down)))
-            for _ in range(4):
-                ft_spec = dataclasses.replace(spec, r=n_i, fault=down)
-                ft = simulator.simulate_fork_join(
-                    key, float(target_rate), n_queries, params,
-                    mode=mode, cluster=ft_spec)
-                faulted_p95_ms = float(ft.quantile(0.95)) * 1e3
-                if (faulted_p95_ms <= slo_seconds * 1e3
-                        or n_i >= _SIM_REPLICA_CAP):
-                    break
-                n_i += 1
+            with span("plan.faults"):
+                for _ in range(4):
+                    ft_spec = dataclasses.replace(spec, r=n_i, fault=down)
+                    ft = simulator.simulate_fork_join(
+                        key, float(target_rate), n_queries, params,
+                        mode=mode, cluster=ft_spec)
+                    faulted_p95_ms = float(ft.quantile(0.95)) * 1e3
+                    if (faulted_p95_ms <= slo_seconds * 1e3
+                            or n_i >= _SIM_REPLICA_CAP):
+                        break
+                    n_i += 1
     elif simulate:
         import warnings
-        reason = ("infeasible SLO" if float(per_replica) <= 1e-9
+        reason = ("infeasible SLO" if per_replica <= 1e-9
                   else f"above the {_SIM_REPLICA_CAP}-replica simulation "
                        "cap")
         warnings.warn(
             f"skipping the simulated cross-check: the plan needs {sim_r} "
             f"replicas ({reason}); run simulate_fork_join directly with "
             "a smaller chunk_size if you really want this",
-            UserWarning, stacklevel=2)
+            UserWarning, stacklevel=3)
     return CapacityPlan(
         n_replicas=n_i,
         servers_per_replica=p,
         total_servers=n_i * p,
         per_replica_rate_qps=rate,
-        response_upper_ms=float(hi) * 1e3,
-        response_lower_ms=float(lo) * 1e3,
-        utilization=float(util),
+        response_upper_ms=upper_ms,
+        response_lower_ms=lower_ms,
+        utilization=util,
         response_simulated_ms=sim_ms,
         response_simulated_p95_ms=sim_p95_ms,
         routing=spec.routing if sim_ms is not None else None,

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import capacity, queueing, sweep
+from repro.obs.spans import span
 
 __all__ = ["HardwareSpec", "TPU_V5E", "RooflineTerms", "ServingModel",
            "serving_params", "plan_serving", "plan_over_grid"]
@@ -246,20 +247,23 @@ def plan_over_grid(
     the scenario axis of either surface across devices — the
     million-scenario planning path of ``examples/global_sweep.py``.
     """
-    if simulate:
-        key = jax.random.PRNGKey(0) if key is None else key
-        result = sweep.sweep_simulated(
-            grid, key, n_queries=20_000 if n_queries is None else n_queries,
-            profile=profile, profile_bin_seconds=profile_bin_seconds,
-            mesh=mesh, **sim_kwargs)
-    else:
-        if (profile is not None or key is not None
-                or n_queries is not None or sim_kwargs):
-            raise ValueError(
-                "profile/key/n_queries/simulation kwargs only take effect "
-                "with simulate=True; the analytic path would silently "
-                "ignore them")
-        result = sweep.sweep_analytical(grid, mesh=mesh)
-    frontier = sweep.extract_frontier(result, slo_seconds, cost_fn=cost_fn,
-                                      quantile=quantile)
+    if not simulate and (profile is not None or key is not None
+                         or n_queries is not None or sim_kwargs):
+        raise ValueError(
+            "profile/key/n_queries/simulation kwargs only take effect "
+            "with simulate=True; the analytic path would silently "
+            "ignore them")
+    with span("grid"):
+        if simulate:
+            key = jax.random.PRNGKey(0) if key is None else key
+            result = sweep.sweep_simulated(
+                grid, key,
+                n_queries=20_000 if n_queries is None else n_queries,
+                profile=profile, profile_bin_seconds=profile_bin_seconds,
+                mesh=mesh, **sim_kwargs)
+        else:
+            result = sweep.sweep_analytical(grid, mesh=mesh)
+        with span("frontier"):
+            frontier = sweep.extract_frontier(
+                result, slo_seconds, cost_fn=cost_fn, quantile=quantile)
     return result, frontier

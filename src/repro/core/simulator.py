@@ -128,6 +128,7 @@ from repro.core.faults import FaultSpec, fault_init, fault_scan
 from repro.core.queueing import ServerParams, service_time_server
 from repro.launch.elastic import AutoscalePolicy, autoscale_init, \
     autoscale_scan
+from repro.obs import spans
 from repro.obs.timeline import TelemetrySpec, Timeline
 
 Array = jax.Array
@@ -167,6 +168,16 @@ _HIST_DECADES_BELOW = 3.0
 _HIST_DECADES_TOTAL = 6.0
 
 
+def _kernel_name(base: str, stage: Optional[str]) -> str:
+    """The scan kernel's name at a call site of ``stage``."""
+    return base if stage is None else f"{base}_{stage}"
+
+
+def _stage(name: str):
+    """Device scope ``stream.<name>`` of one stage of the chunk body."""
+    return jax.named_scope("stream." + name)
+
+
 def maxplus_combine(x, y):
     """Associative composition of affine max-plus maps; y is *later*."""
     a1, b1 = x
@@ -176,7 +187,8 @@ def maxplus_combine(x, y):
 
 def fcfs_completion_times(arrivals: Array, services: Array,
                           impl: str = "auto",
-                          carry: Optional[Array] = None) -> Array:
+                          carry: Optional[Array] = None,
+                          stage: Optional[str] = None) -> Array:
     """Completion times of an FCFS single-server queue.
 
     arrivals: (..., n) nondecreasing along the last axis.
@@ -189,6 +201,9 @@ def fcfs_completion_times(arrivals: Array, services: Array,
     carry: optional (...,) completion time of the work *before* this
     block; seeding composes it on top of the scan, which is how the
     streaming engine chains chunks.
+    stage: the queue level the scan serves (the stream engine passes
+    "broker", "server" or "cache"); it names the kernel
+    ``maxplus_scan_<stage>`` in device traces.
     """
     if impl == "auto":
         from repro.kernels.maxplus_scan.ops import resolve_scan_impl
@@ -197,10 +212,11 @@ def fcfs_completion_times(arrivals: Array, services: Array,
     b = services
     if impl == "pallas":
         from repro.kernels.maxplus_scan import ops as mp_ops
+        name = _kernel_name("maxplus_scan", stage)
         if carry is None:
-            out_a, _ = mp_ops.maxplus_scan(a, b)
+            out_a, _ = mp_ops.maxplus_scan(a, b, name=name)
         else:
-            out_a, _ = mp_ops.maxplus_scan_seeded(a, b, carry)
+            out_a, _ = mp_ops.maxplus_scan_seeded(a, b, carry, name=name)
         return out_a
     out_a, out_b = jax.lax.associative_scan(maxplus_combine, (a, b), axis=-1)
     if carry is not None:
@@ -610,7 +626,8 @@ def _jsq_route(w: Array, gaps: Array, services: Array, live: Array,
 
 
 def _fcfs_segmented(arrivals: Array, services: Array, flags: Array,
-                    carry_per_q: Optional[Array], impl: str) -> Array:
+                    carry_per_q: Optional[Array], impl: str,
+                    stage: Optional[str] = None) -> Array:
     """FCFS completions of many queues packed as contiguous segments.
 
     The fused replicated engine compacts each chunk's queries into
@@ -622,7 +639,8 @@ def _fcfs_segmented(arrivals: Array, services: Array, flags: Array,
     completion time of that queue's prior work), pre-composed at segment
     heads: seeding the head and resetting there is exactly seeding the
     whole segment.  ``impl`` picks `jax.lax.associative_scan` ("xla") or
-    the Pallas segmented kernel ("pallas"; see `ops.interpret_mode`).
+    the Pallas segmented kernel ("pallas"; see `ops.interpret_mode`),
+    named ``maxplus_segment_scan_<stage>`` after the queue level.
     """
     a = arrivals + services
     b = services
@@ -631,7 +649,8 @@ def _fcfs_segmented(arrivals: Array, services: Array, flags: Array,
         a = jnp.where(flags, jnp.maximum(a, carry_per_q + b), a)
     if impl == "pallas":
         from repro.kernels.maxplus_scan import ops as mp_ops
-        out_a, _ = mp_ops.maxplus_segment_scan(a, b, flags)
+        out_a, _ = mp_ops.maxplus_segment_scan(
+            a, b, flags, name=_kernel_name("maxplus_segment_scan", stage))
         return out_a
     from repro.kernels.maxplus_scan.ref import maxplus_segment_combine
     out_a, _, _ = jax.lax.associative_scan(
@@ -751,7 +770,13 @@ def _simulate_stream(
     ``_FAULT_SALT`` stream and all fault carry slots append only when
     present, so ``fault=None`` compiles the bit-identical pre-fault
     program — and an all-up spec reproduces its statistics bitwise.
+
+    Each stage of the chunk body runs under ``jax.named_scope(
+    "stream.<stage>")`` (draws, fault, cache, autoscale, route, broker,
+    server, join, stats, telemetry), which names its device operations in
+    a profiler trace and changes nothing in the program.
     """
+    spans.count("stream/traced")    # runs when the engine is traced
     n_scen = proc.rates.shape[0]
     elastic = autoscale is not None
     faulty = fault is not None
@@ -851,97 +876,102 @@ def _simulate_stream(
             c_idx, trace_gaps_c = x
         else:
             c_idx = x
-        u_gaps, u_brk, services = chunk_random_draws(
-            key, c_idx, n_scen, chunk, p, params, mode,
-            with_gaps=not has_trace)
-        if has_trace:
-            gaps = jnp.broadcast_to(trace_gaps_c[None, :],
-                                    (n_scen, chunk)).astype(dtype)
-        else:
-            # the Sec 4.2 structure: homogeneous Poisson within the chunk,
-            # at the profile rate read off at the chunk's start time
-            rate = jnp.maximum(proc.rate_at(t_origin), 1e-30)
-            gaps = u_gaps / rate[:, None]
-        arrivals = jnp.cumsum(gaps, axis=-1)   # relative to chunk origin
-        # the rebase shift below; captured BEFORE the fused branches
-        # permute `arrivals` into replica-compacted layout
-        last_arrival = arrivals[:, -1]
-        gidx = c_idx * chunk + col
+        with _stage("draws"):
+            u_gaps, u_brk, services = chunk_random_draws(
+                key, c_idx, n_scen, chunk, p, params, mode,
+                with_gaps=not has_trace)
+            if has_trace:
+                gaps = jnp.broadcast_to(trace_gaps_c[None, :],
+                                        (n_scen, chunk)).astype(dtype)
+            else:
+                # the Sec 4.2 structure: homogeneous Poisson within the
+                # chunk, at the profile rate read off at its start time
+                rate = jnp.maximum(proc.rate_at(t_origin), 1e-30)
+                gaps = u_gaps / rate[:, None]
+            arrivals = jnp.cumsum(gaps, axis=-1)  # relative to chunk origin
+            # the rebase shift below; captured BEFORE the fused branches
+            # permute `arrivals` into replica-compacted layout
+            last_arrival = arrivals[:, -1]
+            gidx = c_idx * chunk + col
+            s_broker_c = u_brk * s_broker[:, None]
 
         if faulty:
-            # Degraded servers: rescale the CANONICAL service draws (a
-            # slow disk / throttled CPU on one index partition, on every
-            # replica) before anything consumes them — the autoscaler's
-            # demand feedback, telemetry's busy integrals and both
-            # replica engines all see the degraded times.
-            if fault.degraded:
-                factors = [1.0] * p
-                for srv, f in fault.degraded:
-                    factors[srv % p] *= f
-                services = services * jnp.asarray(
-                    factors, dtype)[None, :, None]
-            # Replica-up mask at each arrival, off the chunking-invariant
-            # recurrence; stochastic transitions draw from the salted
-            # fault stream so the canonical plan is untouched.
-            k_fault = jax.random.fold_in(
-                jax.random.fold_in(key, c_idx), _FAULT_SALT)
-            u_fault = (jax.random.uniform(
-                jax.random.fold_in(k_fault, 0), (n_scen, chunk, r))
-                if fault.mtbf_seconds is not None else None)
-            (f_up,), up_q = fault_scan(
-                fault, r, (f_up,), f_tabs[:, None] + arrivals, gaps,
-                u_fault)
-            up_cnt = jnp.sum(up_q.astype(dtype), axis=-1)  # (S, chunk)
-            f_tabs = f_tabs + last_arrival
+            with _stage("fault"):
+                # Degraded servers: rescale the CANONICAL service draws (a
+                # slow disk / throttled CPU on one index partition, on every
+                # replica) before anything consumes them — the autoscaler's
+                # demand feedback, telemetry's busy integrals and both
+                # replica engines all see the degraded times.
+                if fault.degraded:
+                    factors = [1.0] * p
+                    for srv, f in fault.degraded:
+                        factors[srv % p] *= f
+                    services = services * jnp.asarray(
+                        factors, dtype)[None, :, None]
+                # Replica-up mask at each arrival, off the chunking-invariant
+                # recurrence; stochastic transitions draw from the salted
+                # fault stream so the canonical plan is untouched.
+                k_fault = jax.random.fold_in(
+                    jax.random.fold_in(key, c_idx), _FAULT_SALT)
+                u_fault = (jax.random.uniform(
+                    jax.random.fold_in(k_fault, 0), (n_scen, chunk, r))
+                    if fault.mtbf_seconds is not None else None)
+                (f_up,), up_q = fault_scan(
+                    fault, r, (f_up,), f_tabs[:, None] + arrivals, gaps,
+                    u_fault)
+                up_cnt = jnp.sum(up_q.astype(dtype), axis=-1)  # (S, chunk)
+                f_tabs = f_tabs + last_arrival
 
-        if has_cache:
-            # Result-cache hits short-circuit at their replica's broker
-            # cache: an FCFS queue with Exp(s_cache) service, zero
-            # index-server work — the Eq 8 topology (per-cluster cache),
-            # so the analytic term at lam / r describes the same queue.
-            kc = jax.random.fold_in(
-                jax.random.fold_in(key, c_idx), _CACHE_SALT)
-            kh, ks = jax.random.split(kc)
-            is_hit = jax.random.bernoulli(
-                kh, jnp.broadcast_to(cache_hit[:, None], (n_scen, chunk)))
-            miss_f = 1.0 - is_hit.astype(dtype)
-            t_cache = (jax.random.exponential(ks, (n_scen, chunk))
-                       * cache_service[:, None]
-                       * is_hit.astype(dtype))
-        else:
-            miss_f = None
-
-        s_broker_c = u_brk * s_broker[:, None]
-        if elastic:
-            # Controller feedback in chunk (arrival) order, BEFORE any
-            # routing permutation: each query's server-seconds of demand
-            # (misses only — hits never reach the index servers) plus
-            # the valid-query mask, so the padded tail advances neither
-            # the decision clock nor the cost integral.
-            vf = (gidx < n_queries).astype(dtype)[None, :]
-            dem = jnp.sum(services, axis=1)
+        with _stage("cache"):
             if has_cache:
-                dem = dem * miss_f
-            gaps_v = gaps * vf
-            as_carry, n_act = autoscale_scan(
-                autoscale, p, as_carry, gaps_v, dem * vf,
-                up_frac=up_cnt / r if f_outage else None)
-            n_act_f = n_act.astype(dtype)
-            # the cost integral the policy sweeps price: provisioned
-            # replica-seconds and wall seconds (warmup included — the
-            # fleet is paid for from t=0)
-            rep_secs = rep_secs + jnp.sum(n_act_f * gaps_v, axis=-1)
-            elapsed = elapsed + jnp.sum(gaps_v, axis=-1)
+                # Result-cache hits short-circuit at their replica's broker
+                # cache: an FCFS queue with Exp(s_cache) service, zero
+                # index-server work — the Eq 8 topology (per-cluster cache),
+                # so the analytic term at lam / r describes the same queue.
+                kc = jax.random.fold_in(
+                    jax.random.fold_in(key, c_idx), _CACHE_SALT)
+                kh, ks = jax.random.split(kc)
+                is_hit = jax.random.bernoulli(
+                    kh, jnp.broadcast_to(cache_hit[:, None], (n_scen, chunk)))
+                miss_f = 1.0 - is_hit.astype(dtype)
+                t_cache = (jax.random.exponential(ks, (n_scen, chunk))
+                           * cache_service[:, None]
+                           * is_hit.astype(dtype))
+            else:
+                miss_f = None
+
+        if elastic:
+            with _stage("autoscale"):
+                # Controller feedback in chunk (arrival) order, BEFORE any
+                # routing permutation: each query's server-seconds of demand
+                # (misses only — hits never reach the index servers) plus
+                # the valid-query mask, so the padded tail advances neither
+                # the decision clock nor the cost integral.
+                vf = (gidx < n_queries).astype(dtype)[None, :]
+                dem = jnp.sum(services, axis=1)
+                if has_cache:
+                    dem = dem * miss_f
+                gaps_v = gaps * vf
+                as_carry, n_act = autoscale_scan(
+                    autoscale, p, as_carry, gaps_v, dem * vf,
+                    up_frac=up_cnt / r if f_outage else None)
+                n_act_f = n_act.astype(dtype)
+                # the cost integral the policy sweeps price: provisioned
+                # replica-seconds and wall seconds (warmup included — the
+                # fleet is paid for from t=0)
+                rep_secs = rep_secs + jnp.sum(n_act_f * gaps_v, axis=-1)
+                elapsed = elapsed + jnp.sum(gaps_v, axis=-1)
         if telemetry is not None:
-            # chunk-order captures BEFORE the fused branches permute or
-            # rescale anything: arrival offsets plus each query's
-            # EFFECTIVE demand (cache hits never reach broker/servers,
-            # so misses-only is the busy time conservation requires)
-            tm_arr = arrivals
-            tm_svc = (services * miss_f[:, None, :] if has_cache
-                      else services)
-            tm_brk = s_broker_c * miss_f if has_cache else s_broker_c
-            tm_hit_c = is_hit.astype(dtype) if has_cache else None
+            with _stage("telemetry"):
+                # chunk-order captures BEFORE the fused branches permute or
+                # rescale anything: arrival offsets plus each query's
+                # EFFECTIVE demand (cache hits never reach broker/servers,
+                # so misses-only is the busy time conservation requires)
+                tm_arr = arrivals
+                tm_svc = (services * miss_f[:, None, :] if has_cache
+                          else services)
+                tm_brk = s_broker_c * miss_f if has_cache else s_broker_c
+                tm_hit_c = is_hit.astype(dtype) if has_cache else None
         def _quorum_join(completions, fork_base, axis):
             """Fork-join merge: full quorum, or k-of-p past the timeout.
 
@@ -978,38 +1008,46 @@ def _simulate_stream(
             # single replica: EXACTLY the pre-replication program (the
             # miss mask is the only difference, and only with a cache)
             if has_cache:
-                s_broker_c = s_broker_c * miss_f
-                services = services * miss_f[:, None, :]
-                cache_done = fcfs_completion_times(
-                    arrivals, t_cache, impl=impl, carry=c_cache[:, 0])
-                c_cache_new = (cache_done[:, -1])[:, None]
-            broker_done = fcfs_completion_times(arrivals, s_broker_c,
-                                                impl=impl, carry=c_brk[:, 0])
-            fork = jnp.broadcast_to(broker_done[:, None, :],
-                                    (n_scen, p, chunk))
-            completions = fcfs_completion_times(fork, services, impl=impl,
-                                                carry=c_srv[:, 0])
-            join, degr = _quorum_join(completions, broker_done, axis=1)
-            server0 = completions[:, 0, :]
-            c_brk_new = (broker_done[:, -1])[:, None]
-            c_srv_new = (completions[:, :, -1])[:, None, :]
+                with _stage("cache"):
+                    s_broker_c = s_broker_c * miss_f
+                    services = services * miss_f[:, None, :]
+                    cache_done = fcfs_completion_times(
+                        arrivals, t_cache, impl=impl, carry=c_cache[:, 0],
+                        stage="cache")
+                    c_cache_new = (cache_done[:, -1])[:, None]
+            with _stage("broker"):
+                broker_done = fcfs_completion_times(
+                    arrivals, s_broker_c, impl=impl, carry=c_brk[:, 0],
+                    stage="broker")
+                c_brk_new = (broker_done[:, -1])[:, None]
+            with _stage("server"):
+                fork = jnp.broadcast_to(broker_done[:, None, :],
+                                        (n_scen, p, chunk))
+                completions = fcfs_completion_times(
+                    fork, services, impl=impl, carry=c_srv[:, 0],
+                    stage="server")
+                c_srv_new = (completions[:, :, -1])[:, None, :]
+            with _stage("join"):
+                join, degr = _quorum_join(completions, broker_done, axis=1)
+                server0 = completions[:, 0, :]
             w_jsq_new = w_jsq
         else:
-            live = miss_f if has_cache else jnp.ones_like(gaps)
-            up_route = up_q if f_outage else None
-            assign, spill_q, unav_q = _routing_assign(
-                routing, r, key, c_idx, gidx, n_scen, chunk,
-                n_act=n_act if elastic else None, up=up_route)
-            if assign is None:  # jsq: needs the carried work state
-                routed = _jsq_route(
-                    w_jsq, gaps, services, live, r, dtype,
+            with _stage("route"):
+                live = miss_f if has_cache else jnp.ones_like(gaps)
+                up_route = up_q if f_outage else None
+                assign, spill_q, unav_q = _routing_assign(
+                    routing, r, key, c_idx, gidx, n_scen, chunk,
                     n_act=n_act if elastic else None, up=up_route)
-                if up_route is None:
-                    assign, w_jsq_new = routed
+                if assign is None:  # jsq: needs the carried work state
+                    routed = _jsq_route(
+                        w_jsq, gaps, services, live, r, dtype,
+                        n_act=n_act if elastic else None, up=up_route)
+                    if up_route is None:
+                        assign, w_jsq_new = routed
+                    else:
+                        assign, w_jsq_new, spill_q, unav_q = routed
                 else:
-                    assign, w_jsq_new, spill_q, unav_q = routed
-            else:
-                w_jsq_new = w_jsq
+                    w_jsq_new = w_jsq
 
         if telemetry is not None and r > 1:
             tm_asg = assign          # replica of each chunk-order query
@@ -1021,38 +1059,44 @@ def _simulate_stream(
             # phantom (zero-service) entries cannot delay later real
             # queries (see module doc).  ~r x redundant work — kept for
             # the fused-vs-masked equality tests.
-            mask = (assign[:, None, :]
-                    == jnp.arange(r)[None, :, None]).astype(dtype)
-            # hits occupy their replica's cache queue; only misses enter
-            # its broker + index servers
-            mask_srv = mask * miss_f[:, None, :] if has_cache else mask
-            arr_r = jnp.broadcast_to(arrivals[:, None, :],
-                                     (n_scen, r, chunk))
+            with _stage("route"):
+                mask = (assign[:, None, :]
+                        == jnp.arange(r)[None, :, None]).astype(dtype)
+                # hits occupy their replica's cache queue; only misses
+                # enter its broker + index servers
+                mask_srv = mask * miss_f[:, None, :] if has_cache else mask
+                arr_r = jnp.broadcast_to(arrivals[:, None, :],
+                                         (n_scen, r, chunk))
             if has_cache:
-                cache_done_r = fcfs_completion_times(
-                    arr_r, t_cache[:, None, :] * mask, impl=impl,
-                    carry=c_cache)
-                cache_done = jnp.sum(cache_done_r * mask, axis=1)
-                c_cache_new = cache_done_r[:, :, -1]
-            broker_done_r = fcfs_completion_times(
-                arr_r, s_broker_c[:, None, :] * mask_srv, impl=impl,
-                carry=c_brk)
-            fork = jnp.broadcast_to(broker_done_r[:, :, None, :],
-                                    (n_scen, r, p, chunk))
-            completions = fcfs_completion_times(
-                fork, services[:, None, :, :] * mask_srv[:, :, None, :],
-                impl=impl, carry=c_srv)
-            join_r, degr_r = _quorum_join(completions,
-                                          broker_done_r, axis=2)
-            # read each query off its OWN replica's sample path
-            broker_done = jnp.sum(broker_done_r * mask_srv, axis=1)
-            join = jnp.sum(join_r * mask_srv, axis=1)
-            if f_quorum:
-                degr = jnp.sum(degr_r.astype(dtype) * mask_srv,
-                               axis=1) > 0.0
-            server0 = jnp.sum(completions[:, :, 0, :] * mask_srv, axis=1)
-            c_brk_new = broker_done_r[:, :, -1]
-            c_srv_new = completions[:, :, :, -1]
+                with _stage("cache"):
+                    cache_done_r = fcfs_completion_times(
+                        arr_r, t_cache[:, None, :] * mask, impl=impl,
+                        carry=c_cache, stage="cache")
+                    cache_done = jnp.sum(cache_done_r * mask, axis=1)
+                    c_cache_new = cache_done_r[:, :, -1]
+            with _stage("broker"):
+                broker_done_r = fcfs_completion_times(
+                    arr_r, s_broker_c[:, None, :] * mask_srv, impl=impl,
+                    carry=c_brk, stage="broker")
+                c_brk_new = broker_done_r[:, :, -1]
+            with _stage("server"):
+                fork = jnp.broadcast_to(broker_done_r[:, :, None, :],
+                                        (n_scen, r, p, chunk))
+                completions = fcfs_completion_times(
+                    fork, services[:, None, :, :] * mask_srv[:, :, None, :],
+                    impl=impl, carry=c_srv, stage="server")
+                c_srv_new = completions[:, :, :, -1]
+            with _stage("join"):
+                join_r, degr_r = _quorum_join(completions,
+                                              broker_done_r, axis=2)
+                # read each query off its OWN replica's sample path
+                broker_done = jnp.sum(broker_done_r * mask_srv, axis=1)
+                join = jnp.sum(join_r * mask_srv, axis=1)
+                if f_quorum:
+                    degr = jnp.sum(degr_r.astype(dtype) * mask_srv,
+                                   axis=1) > 0.0
+                server0 = jnp.sum(completions[:, :, 0, :] * mask_srv,
+                                  axis=1)
         elif (routing == "round_robin" and chunk % r == 0
               and not elastic and not f_outage):
             # Fused fast path: with chunk % r == 0 the round-robin
@@ -1073,33 +1117,40 @@ def _simulate_stream(
                 return to_rep(jnp.broadcast_to(x, (n_scen, chunk))
                               ).reshape(n_scen, chunk)
 
-            arr_q = to_rep(arrivals)
-            svc_q = services.reshape(n_scen, p, ct, r).transpose(0, 3, 1, 2)
-            brk_q = to_rep(s_broker_c)
+            with _stage("route"):
+                arr_q = to_rep(arrivals)
+                svc_q = services.reshape(n_scen, p, ct, r).transpose(
+                    0, 3, 1, 2)
+                brk_q = to_rep(s_broker_c)
             if has_cache:
-                miss_q = to_rep(miss_f)
-                brk_q = brk_q * miss_q
-                svc_q = svc_q * miss_q[:, :, None, :]
-                cache_done_q = fcfs_completion_times(
-                    arr_q, to_rep(t_cache), impl=impl, carry=c_cache)
-                cache_done = cache_done_q.reshape(n_scen, chunk)
-                c_cache_new = cache_done_q[..., -1]
-            broker_done_q = fcfs_completion_times(arr_q, brk_q, impl=impl,
-                                                  carry=c_brk)
-            fork = jnp.broadcast_to(broker_done_q[:, :, None, :],
-                                    (n_scen, r, p, ct))
-            completions = fcfs_completion_times(fork, svc_q, impl=impl,
-                                                carry=c_srv)
-            broker_done = broker_done_q.reshape(n_scen, chunk)
-            join_q, degr_q = _quorum_join(completions,
-                                          broker_done_q, axis=2)
-            join = join_q.reshape(n_scen, chunk)
-            if f_quorum:
-                degr = degr_q.reshape(n_scen, chunk)
-            server0 = completions[:, :, 0, :].reshape(n_scen, chunk)
-            c_brk_new = broker_done_q[..., -1]
-            c_srv_new = completions[..., -1]
-            arrivals = arr_q.reshape(n_scen, chunk)
+                with _stage("cache"):
+                    miss_q = to_rep(miss_f)
+                    brk_q = brk_q * miss_q
+                    svc_q = svc_q * miss_q[:, :, None, :]
+                    cache_done_q = fcfs_completion_times(
+                        arr_q, to_rep(t_cache), impl=impl, carry=c_cache,
+                        stage="cache")
+                    cache_done = cache_done_q.reshape(n_scen, chunk)
+                    c_cache_new = cache_done_q[..., -1]
+            with _stage("broker"):
+                broker_done_q = fcfs_completion_times(
+                    arr_q, brk_q, impl=impl, carry=c_brk, stage="broker")
+                c_brk_new = broker_done_q[..., -1]
+            with _stage("server"):
+                fork = jnp.broadcast_to(broker_done_q[:, :, None, :],
+                                        (n_scen, r, p, ct))
+                completions = fcfs_completion_times(
+                    fork, svc_q, impl=impl, carry=c_srv, stage="server")
+                c_srv_new = completions[..., -1]
+            with _stage("join"):
+                broker_done = broker_done_q.reshape(n_scen, chunk)
+                join_q, degr_q = _quorum_join(completions,
+                                              broker_done_q, axis=2)
+                join = join_q.reshape(n_scen, chunk)
+                if f_quorum:
+                    degr = degr_q.reshape(n_scen, chunk)
+                server0 = completions[:, :, 0, :].reshape(n_scen, chunk)
+                arrivals = arr_q.reshape(n_scen, chunk)
         else:
             # Fused general path (random, jsq, uneven round-robin):
             # stable-sort by replica so each replica's queries form a
@@ -1107,261 +1158,273 @@ def _simulate_stream(
             # and run ONE segmented (max, +) scan per queue level.
             # Stable sort preserves arrival order within a replica, so
             # each segment IS that replica's FCFS arrival sequence.
-            order = jnp.argsort(assign, axis=-1, stable=True)
-            asg_s = jnp.take_along_axis(assign, order, axis=-1)
-            flags = jnp.concatenate(
-                [jnp.ones_like(asg_s[:, :1], dtype=bool),
-                 asg_s[:, 1:] != asg_s[:, :-1]], axis=-1)
-            counts = jnp.sum(
-                assign[:, None, :] == jnp.arange(r)[None, :, None],
-                axis=-1)                                  # (S, r)
-            ends = jnp.clip(jnp.cumsum(counts, axis=-1) - 1, 0, None)
+            with _stage("route"):
+                order = jnp.argsort(assign, axis=-1, stable=True)
+                asg_s = jnp.take_along_axis(assign, order, axis=-1)
+                flags = jnp.concatenate(
+                    [jnp.ones_like(asg_s[:, :1], dtype=bool),
+                     asg_s[:, 1:] != asg_s[:, :-1]], axis=-1)
+                counts = jnp.sum(
+                    assign[:, None, :] == jnp.arange(r)[None, :, None],
+                    axis=-1)                              # (S, r)
+                ends = jnp.clip(jnp.cumsum(counts, axis=-1) - 1, 0, None)
 
-            def perm(x):
-                return jnp.take_along_axis(
-                    jnp.broadcast_to(x, (n_scen, chunk)), order, axis=-1)
+                def perm(x):
+                    return jnp.take_along_axis(
+                        jnp.broadcast_to(x, (n_scen, chunk)), order,
+                        axis=-1)
 
-            arrivals = perm(arrivals)
-            svc_s = jnp.take_along_axis(services, order[:, None, :],
-                                        axis=-1)
-            brk_s = perm(s_broker_c)
+                arrivals = perm(arrivals)
+                svc_s = jnp.take_along_axis(services, order[:, None, :],
+                                            axis=-1)
+                brk_s = perm(s_broker_c)
             if has_cache:
-                miss_s = perm(miss_f)
-                brk_s = brk_s * miss_s
-                svc_s = svc_s * miss_s[:, None, :]
-                cache_done = _fcfs_segmented(
-                    arrivals, perm(t_cache), flags,
-                    jnp.take_along_axis(c_cache, asg_s, axis=-1), impl)
-                c_cache_new = jnp.where(
+                with _stage("cache"):
+                    miss_s = perm(miss_f)
+                    brk_s = brk_s * miss_s
+                    svc_s = svc_s * miss_s[:, None, :]
+                    cache_done = _fcfs_segmented(
+                        arrivals, perm(t_cache), flags,
+                        jnp.take_along_axis(c_cache, asg_s, axis=-1), impl,
+                        stage="cache")
+                    c_cache_new = jnp.where(
+                        counts > 0,
+                        jnp.take_along_axis(cache_done, ends, axis=-1),
+                        c_cache)
+            with _stage("broker"):
+                broker_done = _fcfs_segmented(
+                    arrivals, brk_s, flags,
+                    jnp.take_along_axis(c_brk, asg_s, axis=-1), impl,
+                    stage="broker")
+                c_brk_new = jnp.where(
                     counts > 0,
-                    jnp.take_along_axis(cache_done, ends, axis=-1),
-                    c_cache)
-            broker_done = _fcfs_segmented(
-                arrivals, brk_s, flags,
-                jnp.take_along_axis(c_brk, asg_s, axis=-1), impl)
-            fork = jnp.broadcast_to(broker_done[:, None, :],
-                                    (n_scen, p, chunk))
-            carry_srv_q = jnp.take_along_axis(
-                jnp.swapaxes(c_srv, 1, 2), asg_s[:, None, :], axis=-1)
-            completions = _fcfs_segmented(
-                fork, svc_s, flags[:, None, :], carry_srv_q, impl)
-            join, degr = _quorum_join(completions, broker_done, axis=1)
-            server0 = completions[:, 0, :]
-            c_brk_new = jnp.where(
-                counts > 0,
-                jnp.take_along_axis(broker_done, ends, axis=-1), c_brk)
-            srv_ends = jnp.take_along_axis(completions, ends[:, None, :],
-                                           axis=-1)       # (S, p, r)
-            c_srv_new = jnp.where(counts[:, :, None] > 0,
-                                  jnp.swapaxes(srv_ends, 1, 2), c_srv)
+                    jnp.take_along_axis(broker_done, ends, axis=-1), c_brk)
+            with _stage("server"):
+                fork = jnp.broadcast_to(broker_done[:, None, :],
+                                        (n_scen, p, chunk))
+                carry_srv_q = jnp.take_along_axis(
+                    jnp.swapaxes(c_srv, 1, 2), asg_s[:, None, :], axis=-1)
+                completions = _fcfs_segmented(
+                    fork, svc_s, flags[:, None, :], carry_srv_q, impl,
+                    stage="server")
+                srv_ends = jnp.take_along_axis(
+                    completions, ends[:, None, :], axis=-1)   # (S, p, r)
+                c_srv_new = jnp.where(counts[:, :, None] > 0,
+                                      jnp.swapaxes(srv_ends, 1, 2), c_srv)
+            with _stage("join"):
+                join, degr = _quorum_join(completions, broker_done, axis=1)
+                server0 = completions[:, 0, :]
 
-        if f_hedge:
-            # Hedged retries: each attempt races the (possibly partial-
-            # quorum) join with a duplicate fork fired a backoff delay
-            # after the broker fork, served OFF-QUEUE by spare capacity
-            # with fresh draws from the salted fault stream (optimistic:
-            # duplicates add no queue load — the trade Eq 6's
-            # `hedge_threshold` prices).  A response the hedge wins is a
-            # full-quorum result, so it clears the degraded flag.
-            cand = None
-            for h_j, h_delay in enumerate(fault.hedge_delays()):
-                k_h = jax.random.fold_in(k_fault, 1 + h_j)
-                dup = jnp.max(jax.random.exponential(
-                    k_h, (n_scen, p, chunk)), axis=1) * s_mean[:, None]
+        with _stage("join"):
+            if f_hedge:
+                # Hedged retries: each attempt races the (possibly partial-
+                # quorum) join with a duplicate fork fired a backoff delay
+                # after the broker fork, served OFF-QUEUE by spare capacity
+                # with fresh draws from the salted fault stream (optimistic:
+                # duplicates add no queue load — the trade Eq 6's
+                # `hedge_threshold` prices).  A response the hedge wins is a
+                # full-quorum result, so it clears the degraded flag.
+                cand = None
+                for h_j, h_delay in enumerate(fault.hedge_delays()):
+                    k_h = jax.random.fold_in(k_fault, 1 + h_j)
+                    dup = jnp.max(jax.random.exponential(
+                        k_h, (n_scen, p, chunk)), axis=1) * s_mean[:, None]
+                    if perm is not None:
+                        dup = perm(dup)
+                    c = broker_done + h_delay + dup
+                    cand = c if cand is None else jnp.minimum(cand, c)
+                if degr is not None:
+                    degr = degr & (join <= cand)
+                join = jnp.minimum(join, cand)
+
+            if has_cache:
                 if perm is not None:
-                    dup = perm(dup)
-                c = broker_done + h_delay + dup
-                cand = c if cand is None else jnp.minimum(cand, c)
-            if degr is not None:
-                degr = degr & (join <= cand)
-            join = jnp.minimum(join, cand)
-
-        if has_cache:
+                    is_hit = perm(is_hit)
+                if degr is not None:
+                    degr = degr & ~is_hit   # hits never fork: always whole
+                resp_cache = cache_done - arrivals
+                response = jnp.where(is_hit, resp_cache, join - arrivals)
+                broker_res = jnp.where(is_hit, resp_cache,
+                                       broker_done - arrivals)
+                cluster_res = jnp.where(is_hit, 0.0, join - broker_done)
+                server_res = jnp.where(is_hit, 0.0, server0 - broker_done)
+            else:
+                response = join - arrivals
+                broker_res = broker_done - arrivals
+                cluster_res = join - broker_done
+                server_res = server0 - broker_done
+                c_cache_new = c_cache
+        with _stage("stats"):
+            mf = ((gidx >= n_warm) & (gidx < n_queries)).astype(dtype)[None, :]
+            mf0 = mf                 # chunk-order copy for chunk-order sums
             if perm is not None:
-                is_hit = perm(is_hit)
-            if degr is not None:
-                degr = degr & ~is_hit   # hits never fork: always whole
-            resp_cache = cache_done - arrivals
-            response = jnp.where(is_hit, resp_cache, join - arrivals)
-            broker_res = jnp.where(is_hit, resp_cache,
-                                   broker_done - arrivals)
-            cluster_res = jnp.where(is_hit, 0.0, join - broker_done)
-            server_res = jnp.where(is_hit, 0.0, server0 - broker_done)
-        else:
-            response = join - arrivals
-            broker_res = broker_done - arrivals
-            cluster_res = join - broker_done
-            server_res = server0 - broker_done
-            c_cache_new = c_cache
-        mf = ((gidx >= n_warm) & (gidx < n_queries)).astype(dtype)[None, :]
-        mf0 = mf                 # chunk-order copy for chunk-order sums
-        if perm is not None:
-            mf = perm(mf)
-        count = count + jnp.broadcast_to(jnp.sum(mf, -1), (n_scen,))
-        s_resp = s_resp + jnp.sum(response * mf, -1)
-        ss_resp = ss_resp + jnp.sum(response * response * mf, -1)
-        s_br = s_br + jnp.sum(broker_res * mf, -1)
-        s_cl = s_cl + jnp.sum(cluster_res * mf, -1)
-        s_sv = s_sv + jnp.sum(server_res * mf, -1)
-        if faulty:
-            # spill/unavail live in chunk (arrival) order, the degraded
-            # flag in the engine's (possibly permuted) layout; the sums
-            # are permutation-invariant either way.
-            if f_outage and r > 1:
-                s_spill = s_spill + jnp.sum(
-                    spill_q.astype(dtype) * mf0, -1)
-                s_unav = s_unav + jnp.sum(
-                    unav_q.astype(dtype) * mf0, -1)
-            elif f_outage:       # r == 1: down means nowhere to route
-                s_unav = s_unav + jnp.sum(
-                    (1.0 - up_q[:, :, 0].astype(dtype)) * mf0, -1)
-            if degr is not None:
-                s_degr = s_degr + jnp.sum(degr.astype(dtype) * mf, -1)
+                mf = perm(mf)
+            count = count + jnp.broadcast_to(jnp.sum(mf, -1), (n_scen,))
+            s_resp = s_resp + jnp.sum(response * mf, -1)
+            ss_resp = ss_resp + jnp.sum(response * response * mf, -1)
+            s_br = s_br + jnp.sum(broker_res * mf, -1)
+            s_cl = s_cl + jnp.sum(cluster_res * mf, -1)
+            s_sv = s_sv + jnp.sum(server_res * mf, -1)
+            if faulty:
+                # spill/unavail live in chunk (arrival) order, the degraded
+                # flag in the engine's (possibly permuted) layout; the sums
+                # are permutation-invariant either way.
+                if f_outage and r > 1:
+                    s_spill = s_spill + jnp.sum(
+                        spill_q.astype(dtype) * mf0, -1)
+                    s_unav = s_unav + jnp.sum(
+                        unav_q.astype(dtype) * mf0, -1)
+                elif f_outage:       # r == 1: down means nowhere to route
+                    s_unav = s_unav + jnp.sum(
+                        (1.0 - up_q[:, :, 0].astype(dtype)) * mf0, -1)
+                if degr is not None:
+                    s_degr = s_degr + jnp.sum(degr.astype(dtype) * mf, -1)
 
-        bins = jnp.clip(
-            jnp.floor((jnp.log(jnp.maximum(response, 1e-30))
-                       - hist_log_lo[:, None]) / hist_log_step[:, None]),
-            0, hist_bins - 1).astype(jnp.int32)
-        hist = hist.at[rows, bins].add(
-            jnp.broadcast_to(mf, (n_scen, chunk)))
+            bins = jnp.clip(
+                jnp.floor((jnp.log(jnp.maximum(response, 1e-30))
+                           - hist_log_lo[:, None]) / hist_log_step[:, None]),
+                0, hist_bins - 1).astype(jnp.int32)
+            hist = hist.at[rows, bins].add(
+                jnp.broadcast_to(mf, (n_scen, chunk)))
 
-        if tap_size > 0:
-            # Reservoir via random priorities (A-Res with equal weights):
-            # every valid query gets an iid U(0,1) priority and the tap
-            # keeps the tap_size largest seen so far — a uniform sample
-            # without replacement, one top_k per chunk, O(tap) state.
-            k_tap = jax.random.fold_in(
-                jax.random.fold_in(key, c_idx), _TAP_SALT)
-            pri = jax.random.uniform(k_tap, (n_scen, chunk), dtype)
-            if perm is not None:
-                pri = perm(pri)
-            pri = jnp.where(mf > 0, pri, -jnp.inf)
-            cat_pri = jnp.concatenate([tap_pri, pri], axis=-1)
-            cat_val = jnp.concatenate(
-                [tap_val, jnp.broadcast_to(response, (n_scen, chunk))],
-                axis=-1)
-            tap_pri, idx = jax.lax.top_k(cat_pri, tap_size)
-            tap_val = jnp.take_along_axis(cat_val, idx, axis=-1)
+            if tap_size > 0:
+                # Reservoir via random priorities (A-Res with equal weights):
+                # every valid query gets an iid U(0,1) priority and the tap
+                # keeps the tap_size largest seen so far — a uniform sample
+                # without replacement, one top_k per chunk, O(tap) state.
+                k_tap = jax.random.fold_in(
+                    jax.random.fold_in(key, c_idx), _TAP_SALT)
+                pri = jax.random.uniform(k_tap, (n_scen, chunk), dtype)
+                if perm is not None:
+                    pri = perm(pri)
+                pri = jnp.where(mf > 0, pri, -jnp.inf)
+                cat_pri = jnp.concatenate([tap_pri, pri], axis=-1)
+                cat_val = jnp.concatenate(
+                    [tap_val, jnp.broadcast_to(response, (n_scen, chunk))],
+                    axis=-1)
+                tap_pri, idx = jax.lax.top_k(cat_pri, tap_size)
+                tap_val = jnp.take_along_axis(cat_val, idx, axis=-1)
 
         if telemetry is not None:
-            # Timeline tallies (no RNG, so the canonical draw plan is
-            # untouched).  Bin by arrival time on the UNWRAPPED absolute
-            # clock; warmup is included by design (transients are the
-            # signal), only the tail padding is excluded.  Arrivals are
-            # nondecreasing within a chunk, so each bin is a CONTIGUOUS
-            # run of queries: per-bin sums are differences of one
-            # prefix sum read at the bin-edge positions (vmapped
-            # searchsorted) — O(chunk) per channel, an order of
-            # magnitude cheaper than scatter-adds or one-hot
-            # contractions inside the scan, and the per-chunk total
-            # telescopes exactly (conservation is bit-exact).
-            t_arr = t_abs[:, None] + tm_arr          # (S, chunk), sorted
-            # padded tail queries (gidx >= n_queries) are a SUFFIX of
-            # the sorted chunk, so clamping the bin-edge positions at
-            # n_valid excludes them for free — no valid-mask multiply
-            # on any channel
-            n_valid = jnp.clip(n_queries - c_idx * chunk, 0, chunk)
-            edges = tl_bin_w[:, None] * jnp.arange(
-                tl_bins, dtype=dtype)[None, :]        # (S, B)
-            pos = jax.vmap(jnp.searchsorted)(t_arr, edges)
-            pos = jnp.minimum(
-                jnp.concatenate(
-                    [pos, jnp.full((n_scen, 1), chunk, pos.dtype)],
-                    axis=-1),
-                n_valid)                              # (S, B + 1)
+            with _stage("telemetry"):
+                # Timeline tallies (no RNG, so the canonical draw plan is
+                # untouched).  Bin by arrival time on the UNWRAPPED absolute
+                # clock; warmup is included by design (transients are the
+                # signal), only the tail padding is excluded.  Arrivals are
+                # nondecreasing within a chunk, so each bin is a CONTIGUOUS
+                # run of queries: per-bin sums are differences of one
+                # prefix sum read at the bin-edge positions (vmapped
+                # searchsorted) — O(chunk) per channel, an order of
+                # magnitude cheaper than scatter-adds or one-hot
+                # contractions inside the scan, and the per-chunk total
+                # telescopes exactly (conservation is bit-exact).
+                t_arr = t_abs[:, None] + tm_arr          # (S, chunk), sorted
+                # padded tail queries (gidx >= n_queries) are a SUFFIX of
+                # the sorted chunk, so clamping the bin-edge positions at
+                # n_valid excludes them for free — no valid-mask multiply
+                # on any channel
+                n_valid = jnp.clip(n_queries - c_idx * chunk, 0, chunk)
+                edges = tl_bin_w[:, None] * jnp.arange(
+                    tl_bins, dtype=dtype)[None, :]        # (S, B)
+                pos = jax.vmap(jnp.searchsorted)(t_arr, edges)
+                pos = jnp.minimum(
+                    jnp.concatenate(
+                        [pos, jnp.full((n_scen, 1), chunk, pos.dtype)],
+                        axis=-1),
+                    n_valid)                              # (S, B + 1)
 
-            # Two-level prefix sums: a full cumsum over the chunk is
-            # multi-pass under XLA, but prefixes are only ever READ at
-            # the B + 1 edge positions.  So: one pass of per-block
-            # partial sums, a tiny cumsum over the ~chunk/blk blocks,
-            # and a masked intra-block sum just at the edges — ~one
-            # read of the data per channel instead of a scan.
-            blk = 1
-            while (blk < 128 and chunk % (blk * 2) == 0
-                   and blk * (tl_bins + 1) < chunk):
-                blk *= 2
-            nb = chunk // blk
-            e_blk = pos // blk                        # (S, B + 1)
-            e_within = pos - e_blk * blk
-            e_blk_c = jnp.minimum(e_blk, nb - 1)
-            e_within = jnp.where(e_blk > e_blk_c, blk, e_within)
-            intra_mask = (jnp.arange(blk) < e_within[..., None]
-                          ).astype(dtype)             # (S, B + 1, blk)
+                # Two-level prefix sums: a full cumsum over the chunk is
+                # multi-pass under XLA, but prefixes are only ever READ at
+                # the B + 1 edge positions.  So: one pass of per-block
+                # partial sums, a tiny cumsum over the ~chunk/blk blocks,
+                # and a masked intra-block sum just at the edges — ~one
+                # read of the data per channel instead of a scan.
+                blk = 1
+                while (blk < 128 and chunk % (blk * 2) == 0
+                       and blk * (tl_bins + 1) < chunk):
+                    blk *= 2
+                nb = chunk // blk
+                e_blk = pos // blk                        # (S, B + 1)
+                e_within = pos - e_blk * blk
+                e_blk_c = jnp.minimum(e_blk, nb - 1)
+                e_within = jnp.where(e_blk > e_blk_c, blk, e_within)
+                intra_mask = (jnp.arange(blk) < e_within[..., None]
+                              ).astype(dtype)             # (S, B + 1, blk)
 
-            def bin_sums(w):
-                """(S, ..., chunk) weights -> (S, ..., B) per-bin sums."""
-                lead = (1,) * (w.ndim - 2)
-                wb = w.reshape(w.shape[:-1] + (nb, blk))
-                blocks = jnp.cumsum(jnp.sum(wb, axis=-1), axis=-1)
-                eb = jnp.broadcast_to(
-                    e_blk_c.reshape((n_scen,) + lead + (tl_bins + 1,)),
-                    w.shape[:-1] + (tl_bins + 1,))
-                pre = jnp.where(
-                    eb > 0,
-                    jnp.take_along_axis(blocks, jnp.maximum(eb - 1, 0),
-                                        axis=-1),
-                    jnp.zeros_like(blocks[..., :1]))
-                wsel = jnp.take_along_axis(wb, eb[..., None], axis=-2)
-                take = pre + jnp.sum(
-                    wsel * intra_mask.reshape(
-                        (n_scen,) + lead + (tl_bins + 1, blk)),
-                    axis=-1)
-                return take[..., 1:] - take[..., :-1]
+                def bin_sums(w):
+                    """(S, ..., chunk) weights -> (S, ..., B) per-bin sums."""
+                    lead = (1,) * (w.ndim - 2)
+                    wb = w.reshape(w.shape[:-1] + (nb, blk))
+                    blocks = jnp.cumsum(jnp.sum(wb, axis=-1), axis=-1)
+                    eb = jnp.broadcast_to(
+                        e_blk_c.reshape((n_scen,) + lead + (tl_bins + 1,)),
+                        w.shape[:-1] + (tl_bins + 1,))
+                    pre = jnp.where(
+                        eb > 0,
+                        jnp.take_along_axis(blocks, jnp.maximum(eb - 1, 0),
+                                            axis=-1),
+                        jnp.zeros_like(blocks[..., :1]))
+                    wsel = jnp.take_along_axis(wb, eb[..., None], axis=-2)
+                    take = pre + jnp.sum(
+                        wsel * intra_mask.reshape(
+                            (n_scen,) + lead + (tl_bins + 1, blk)),
+                        axis=-1)
+                    return take[..., 1:] - take[..., :-1]
 
-            # counts need no cumsum at all: bins are contiguous runs, so
-            # the per-bin count IS the difference of the edge positions
-            cnt_inc = (pos[:, 1:] - pos[:, :-1]).astype(dtype)  # (S, B)
-            tm_count = tm_count + cnt_inc
-            if r == 1:
-                # single replica: every per-replica channel collapses to
-                # the plain one — skip the assignment mask entirely
-                tm_rc = tm_rc + cnt_inc[:, :, None]
-                tm_bb = tm_bb + bin_sums(tm_brk)[:, :, None]
-                tm_bs = tm_bs + jnp.moveaxis(
-                    bin_sums(tm_svc), -1, 1)[:, :, None, :]
-            else:
-                mask_a = (tm_asg[:, None, :]
-                          == jnp.arange(r, dtype=jnp.int32)[None, :, None]
-                          ).astype(dtype)             # (S, r, chunk)
-                tm_rc = tm_rc + jnp.swapaxes(bin_sums(mask_a), 1, 2)
-                tm_bb = tm_bb + jnp.swapaxes(
-                    bin_sums(mask_a * tm_brk[:, None, :]), 1, 2)
-                tm_bs = tm_bs + jnp.moveaxis(
-                    bin_sums(mask_a[:, :, None, :]
-                             * tm_svc[:, None, :, :]),
-                    -1, 1)                            # (S, B, r, p)
-            if has_cache:
-                tm_hit = tm_hit + bin_sums(tm_hit_c)
-            # response-side tallies live in the engine's layout — bring
-            # them BACK to (sorted) chunk order via the inverse permute
-            if perm is not None:
-                inv = jnp.argsort(
-                    perm(jnp.arange(chunk, dtype=jnp.int32)), axis=-1)
-                resp_c = jnp.take_along_axis(
-                    jnp.broadcast_to(response, (n_scen, chunk)), inv,
-                    axis=-1)
-            else:
-                resp_c = response
-            tm_resp = tm_resp + bin_sums(resp_c)
-            tm_slo = tm_slo + bin_sums((resp_c > tl_slo).astype(dtype))
-            if elastic:
-                # the autoscaler trajectory: active fleet size summed
-                # over each bin's arrivals (n_act is in chunk order)
-                tm_act = tm_act + bin_sums(n_act_f)
-            if faulty:
-                # fault trajectory: surviving-replica count and spills
-                # are in chunk order; the degraded flag rides the same
-                # inverse permute as the responses
-                tm_up = tm_up + bin_sums(up_cnt)
-                if f_outage and r > 1:
-                    tm_spill = tm_spill + bin_sums(spill_q.astype(dtype))
-                if degr is not None:
-                    dg = jnp.broadcast_to(degr.astype(dtype),
-                                          (n_scen, chunk))
-                    if perm is not None:
-                        dg = jnp.take_along_axis(dg, inv, axis=-1)
-                    tm_degr = tm_degr + bin_sums(dg)
-            t_abs = t_abs + last_arrival
+                # counts need no cumsum at all: bins are contiguous runs, so
+                # the per-bin count IS the difference of the edge positions
+                cnt_inc = (pos[:, 1:] - pos[:, :-1]).astype(dtype)  # (S, B)
+                tm_count = tm_count + cnt_inc
+                if r == 1:
+                    # single replica: every per-replica channel collapses to
+                    # the plain one — skip the assignment mask entirely
+                    tm_rc = tm_rc + cnt_inc[:, :, None]
+                    tm_bb = tm_bb + bin_sums(tm_brk)[:, :, None]
+                    tm_bs = tm_bs + jnp.moveaxis(
+                        bin_sums(tm_svc), -1, 1)[:, :, None, :]
+                else:
+                    mask_a = (tm_asg[:, None, :]
+                              == jnp.arange(r, dtype=jnp.int32)[None, :, None]
+                              ).astype(dtype)             # (S, r, chunk)
+                    tm_rc = tm_rc + jnp.swapaxes(bin_sums(mask_a), 1, 2)
+                    tm_bb = tm_bb + jnp.swapaxes(
+                        bin_sums(mask_a * tm_brk[:, None, :]), 1, 2)
+                    tm_bs = tm_bs + jnp.moveaxis(
+                        bin_sums(mask_a[:, :, None, :]
+                                 * tm_svc[:, None, :, :]),
+                        -1, 1)                            # (S, B, r, p)
+                if has_cache:
+                    tm_hit = tm_hit + bin_sums(tm_hit_c)
+                # response-side tallies live in the engine's layout — bring
+                # them BACK to (sorted) chunk order via the inverse permute
+                if perm is not None:
+                    inv = jnp.argsort(
+                        perm(jnp.arange(chunk, dtype=jnp.int32)), axis=-1)
+                    resp_c = jnp.take_along_axis(
+                        jnp.broadcast_to(response, (n_scen, chunk)), inv,
+                        axis=-1)
+                else:
+                    resp_c = response
+                tm_resp = tm_resp + bin_sums(resp_c)
+                tm_slo = tm_slo + bin_sums((resp_c > tl_slo).astype(dtype))
+                if elastic:
+                    # the autoscaler trajectory: active fleet size summed
+                    # over each bin's arrivals (n_act is in chunk order)
+                    tm_act = tm_act + bin_sums(n_act_f)
+                if faulty:
+                    # fault trajectory: surviving-replica count and spills
+                    # are in chunk order; the degraded flag rides the same
+                    # inverse permute as the responses
+                    tm_up = tm_up + bin_sums(up_cnt)
+                    if f_outage and r > 1:
+                        tm_spill = tm_spill + bin_sums(spill_q.astype(dtype))
+                    if degr is not None:
+                        dg = jnp.broadcast_to(degr.astype(dtype),
+                                              (n_scen, chunk))
+                        if perm is not None:
+                            dg = jnp.take_along_axis(dg, inv, axis=-1)
+                        tm_degr = tm_degr + bin_sums(dg)
+                t_abs = t_abs + last_arrival
 
         shift = last_arrival
         c_brk_s = c_brk_new - shift[:, None]

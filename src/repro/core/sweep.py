@@ -47,6 +47,7 @@ from repro.core.cluster import ClusterSpec, resolve_cluster
 from repro.core.faults import FaultSpec
 from repro.core.queueing import ServerParams
 from repro.launch.elastic import AutoscalePolicy
+from repro.obs.spans import span
 
 Array = jax.Array
 ArrayLike = Union[Array, Sequence[float], float]
@@ -668,10 +669,10 @@ def sweep_simulated(
                 degraded_sum=jnp.zeros_like(tl.count))
         return dataclasses.replace(res, **kw)
 
-    p_slabs = []
+    results = []
     for i in range(n_p):
         p = _static_count(p_axis[i], "server")
-        cfg_slabs = []
+        cfg_results = []
         for j in range(n_cfg):
             if policies is not None:
                 cell = ClusterSpec(routing=spec.routing,
@@ -691,19 +692,25 @@ def sweep_simulated(
                                    replica_impl=spec.replica_impl)
             params_ij = ServerParams(
                 **{n: v[i, j] for n, v in field_slabs.items()})
-            res = dispatch(keys[i * n_cfg + j], lam_slabs[i, j],
-                           params_ij, p, cell)
+            # one span per (p, config) cell: the dispatch returns as soon
+            # as the program is enqueued, so the span is host-side work
+            with span("sweep.dispatch", p=p, r=cell.r):
+                res = dispatch(keys[i * n_cfg + j], lam_slabs[i, j],
+                               params_ij, p, cell)
             if faults is not None:
                 res = fill_fault_channels(res, cell.r)
-            slab_shape = (shape[0], shape[2], shape[3], shape[4])
-            cfg_slabs.append(jax.tree_util.tree_map(
-                lambda x: x.reshape(slab_shape + x.shape[1:]), res))
+            cfg_results.append(res)
+        results.append(cfg_results)
+    with span("sweep.gather"):
+        slab_shape = (shape[0], shape[2], shape[3], shape[4])
         # stack the replica/policy axis behind (L,C,D,H) -> axis 4
-        p_slabs.append(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs, axis=4), *cfg_slabs))
-    # stack the p axis into position 1 -> (L,P,C,D,H,R)
-    stats = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs, axis=1), *p_slabs)
+        p_slabs = [jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(
+                [x.reshape(slab_shape + x.shape[1:]) for x in xs], axis=4),
+            *cfg_results) for cfg_results in results]
+        # stack the p axis into position 1 -> (L,P,C,D,H,R)
+        stats = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs, axis=1), *p_slabs)
     return SimSweepResult(grid=grid, stats=stats)
 
 

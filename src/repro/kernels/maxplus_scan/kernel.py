@@ -130,11 +130,13 @@ def maxplus_scan_pallas(
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
     interpret: bool = False,
+    name: str = "maxplus_scan",
 ) -> tuple[jax.Array, jax.Array]:
     """Inclusive max-plus scan along axis -1 of (rows, length) arrays.
 
     Both dims must already be padded to multiples of (row_tile, block_len);
     `ops.maxplus_scan` handles padding/reshaping for arbitrary shapes.
+    ``name`` is the kernel's name in compiled programs and device traces.
     """
     rows, length = a.shape
     assert rows % row_tile == 0 and length % block_len == 0, (rows, length)
@@ -158,6 +160,7 @@ def maxplus_scan_pallas(
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(a, b)
     return out_a, out_b
 
@@ -170,13 +173,15 @@ def maxplus_segment_scan_pallas(
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
     interpret: bool = False,
+    name: str = "maxplus_segment_scan",
 ) -> tuple[jax.Array, jax.Array]:
     """Segmented inclusive max-plus scan along axis -1.
 
     ``f`` holds float 0/1 reset flags (1 = this element starts a new
     segment).  Shapes/dtypes must match ``a``; both dims must be padded
     to (row_tile, block_len) multiples — `ops.maxplus_segment_scan`
-    handles arbitrary shapes.
+    handles arbitrary shapes.  ``name`` names the kernel, as in
+    `maxplus_scan_pallas`.
     """
     rows, length = a.shape
     assert rows % row_tile == 0 and length % block_len == 0, (rows, length)
@@ -201,5 +206,6 @@ def maxplus_segment_scan_pallas(
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(a, b, f)
     return out_a, out_b

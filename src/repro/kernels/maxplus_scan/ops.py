@@ -77,15 +77,20 @@ def maxplus_scan(
     *,
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
+    name: str = "maxplus_scan",
 ) -> tuple[jax.Array, jax.Array]:
-    """Inclusive (max, +) scan along the last axis; any leading shape."""
+    """Inclusive (max, +) scan along the last axis; any leading shape.
+
+    ``name`` names the kernel in compiled programs and device traces.
+    """
     return _maxplus_scan(a, b, block_len=block_len, row_tile=row_tile,
-                         interpret=interpret_mode())
+                         interpret=interpret_mode(), name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("block_len", "row_tile",
-                                             "interpret"))
-def _maxplus_scan(a, b, *, block_len: int, row_tile: int, interpret: bool):
+                                             "interpret", "name"))
+def _maxplus_scan(a, b, *, block_len: int, row_tile: int, interpret: bool,
+                  name: str):
     orig_shape = a.shape
     n = orig_shape[-1]
     rows = 1
@@ -102,7 +107,8 @@ def _maxplus_scan(a, b, *, block_len: int, row_tile: int, interpret: bool):
         b2 = jnp.pad(b2, ((0, pad_r), (0, pad_n)), constant_values=0.0)
 
     out_a, out_b = maxplus_scan_pallas(
-        a2, b2, block_len=block_len, row_tile=row_tile, interpret=interpret)
+        a2, b2, block_len=block_len, row_tile=row_tile, interpret=interpret,
+        name=name)
     out_a = out_a[:rows, :n].reshape(orig_shape)
     out_b = out_b[:rows, :n].reshape(orig_shape)
     return out_a, out_b
@@ -115,6 +121,7 @@ def maxplus_segment_scan(
     *,
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
+    name: str = "maxplus_segment_scan",
 ) -> tuple[jax.Array, jax.Array]:
     """Segmented inclusive (max, +) scan along the last axis.
 
@@ -124,16 +131,17 @@ def maxplus_segment_scan(
     are compacted into contiguous segments of one row and scanned in a
     single kernel pass.  Any leading shape; padding uses the semiring
     identity (a = -inf, b = 0, f = 0), which cannot disturb real lanes.
+    ``name`` names the kernel, as in :func:`maxplus_scan`.
     """
     return _maxplus_segment_scan(a, b, f, block_len=block_len,
                                  row_tile=row_tile,
-                                 interpret=interpret_mode())
+                                 interpret=interpret_mode(), name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("block_len", "row_tile",
-                                             "interpret"))
+                                             "interpret", "name"))
 def _maxplus_segment_scan(a, b, f, *, block_len: int, row_tile: int,
-                          interpret: bool):
+                          interpret: bool, name: str):
     orig_shape = a.shape
     n = orig_shape[-1]
     rows = 1
@@ -153,7 +161,7 @@ def _maxplus_segment_scan(a, b, f, *, block_len: int, row_tile: int,
 
     out_a, out_b = maxplus_segment_scan_pallas(
         a2, b2, f2, block_len=block_len, row_tile=row_tile,
-        interpret=interpret)
+        interpret=interpret, name=name)
     out_a = out_a[:rows, :n].reshape(orig_shape)
     out_b = out_b[:rows, :n].reshape(orig_shape)
     return out_a, out_b
@@ -167,6 +175,7 @@ def maxplus_scan_seeded(
     *,
     block_len: int = DEFAULT_BLOCK_LEN,
     row_tile: int = DEFAULT_ROW_TILE,
+    name: str = "maxplus_scan",
 ) -> tuple[jax.Array, jax.Array]:
     """Inclusive (max, +) scan seeded by the carry of everything earlier.
 
@@ -182,7 +191,7 @@ def maxplus_scan_seeded(
     ``carry_a``/``carry_b`` broadcast against ``a.shape[:-1]``.
     """
     out_a, out_b = maxplus_scan(a, b, block_len=block_len,
-                                row_tile=row_tile)
+                                row_tile=row_tile, name=name)
     carry_a = jnp.asarray(carry_a)
     if carry_b is None:
         carry_b = jnp.zeros_like(carry_a)

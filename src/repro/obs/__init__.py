@@ -16,6 +16,10 @@ Three layers, one per way of looking at a running cluster:
 
 ``python -m repro.obs.report`` renders all three as a text dashboard.
 
+`repro.obs.spans` instruments the planner itself rather than the
+simulated cluster: ``repro.*`` host spans in a ``jax.profiler`` trace and
+``/repro/*`` ``jax.monitoring`` events when a step is traced.
+
 Import discipline: this package root re-exports ONLY the timeline layer
 — `repro.core.simulator` imports it, so anything heavier (trace export
 and profiling import calibrate/kernels, which import the simulator)

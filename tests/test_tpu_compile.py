@@ -66,5 +66,10 @@ def test_stream_program_compiles_with_kernel(one_chip, monkeypatch, r):
         simulator.simulate_fork_join_batch, n_queries=3 * 1024, p=100,
         impl="pallas", chunk_size=1024, cluster=ClusterSpec(r=r))).lower(
             key, vec, ServerParams(*(vec,) * 6)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # each scan kernel is named after its stage, and keeps "maxplus"
+    for stage in ("broker", "server"):
+        assert f"%maxplus_scan_{stage}" in text
+        assert f"stream.{stage}/" in text
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
