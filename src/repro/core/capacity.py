@@ -11,6 +11,7 @@ All sweeps evaluate as single XLA programs over (lambda-grid x scenario).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -105,6 +106,7 @@ def upper_bound_curve(lam_grid: Array, params: ServerParams) -> Array:
     return hi
 
 
+@functools.partial(jax.jit, static_argnames=("iters",))
 def max_rate_under_slo(
     params: ServerParams,
     slo_seconds: float,
@@ -117,6 +119,10 @@ def max_rate_under_slo(
     result_cache: optional (hit_result, s_broker_cache_hit) enabling Eq 8.
     R(lambda) is monotone increasing up to saturation, so bisection on
     [0, saturation_rate) is exact to float precision.
+
+    One compiled program per input structure: the parameters, the SLO and
+    the cache's values are traced, so calls that differ only in those
+    values reuse it; ``result_cache=None`` and a pair are two programs.
     """
     lam_max = queueing.saturation_rate(params) * (1.0 - 1e-6)
 
@@ -132,7 +138,7 @@ def max_rate_under_slo(
     hi = lam_max
 
     def body(state, _):
-        count("plan/size_traced")    # runs when the bisection is traced
+        count("plan/size_traced")    # runs when the sizing is traced
         lo, hi = state
         mid = 0.5 * (lo + hi)
         ok = response(mid) <= slo_seconds
@@ -286,25 +292,34 @@ def plan_capacity(
                      key, n_queries, mode, k_down)
 
 
+@jax.jit
+def _size(params, target_rate, slo_seconds, cache):
+    """The Section 6 sizing as one program: the fault-free replica count,
+    the per-replica rate, and at the survivor rate ``target / max(n, 1)``
+    the Eq 7 bounds (Eq 8 upper with a cache) and the utilization."""
+    n, per_replica = replicas_needed(params, target_rate, slo_seconds,
+                                     result_cache=cache)
+    rate = target_rate / jnp.maximum(n, 1)
+    lo, hi = queueing.response_time_bounds(rate, params)
+    if cache is not None:
+        hi = queueing.response_time_with_result_cache(rate, params, *cache)
+    util = queueing.utilization(rate, queueing.service_time_server(params))
+    return n, per_replica, lo, hi, util
+
+
 def _plan(params, target_rate, slo_seconds, spec, simulate, key,
           n_queries, mode, k_down) -> CapacityPlan:
     """`plan_capacity` after its arguments are checked: the sizing, then
     the simulated cross-check, each under its own span."""
     cache = spec.result_cache
     with span("plan.size"):
-        n, per_replica = replicas_needed(
-            params, target_rate, slo_seconds, result_cache=cache)
+        n, per_replica, lo, hi, util = jax.device_get(_size(
+            params, float(target_rate), float(slo_seconds), cache))
         # N+k: the bound must hold at the SURVIVOR rate target / n_base,
         # so provisioning gains k spares on top of the fault-free answer
         n_i = int(n) + k_down
         rate = float(target_rate) / max(int(n), 1)
-        lo, hi = queueing.response_time_bounds(rate, params)
-        if cache is not None:
-            hi = queueing.response_time_with_result_cache(
-                rate, params, *cache)
-        p = int(jnp.asarray(params.p))
-        util = queueing.utilization(
-            rate, queueing.service_time_server(params))
+        p = int(params.p)
         upper_ms, lower_ms = float(hi) * 1e3, float(lo) * 1e3
         util = float(util)
         per_replica = float(per_replica)

@@ -1,9 +1,18 @@
 """Section-6 case-study reproduction: the paper's own numbers."""
 
+import math
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import capacity, queueing
+from repro.core.cluster import ClusterSpec
+
+S6_CACHE = (0.5, 0.069e-3)
+SCENARIOS = ("baseline", "memory+disks", "memory+cpus", "cpus+disks",
+             "memory+cpus+disks")
 
 
 def test_broker_fit_345ms_at_p100():
@@ -86,3 +95,90 @@ def test_slo_solver_is_exact_boundary():
     _, above = queueing.response_time_bounds(float(lam) * 1.02, p4)
     assert float(at) <= 0.300 + 1e-5
     assert float(above) > 0.300
+
+
+def test_sizing_traced_at_most_once_per_structure(events):
+    """The sizing is one program per input structure: calls that differ
+    in rate and SLO reuse it, and a result cache makes a second one."""
+    p4 = capacity.scenario("memory+cpus+disks")
+    for cache in (None, S6_CACHE):
+        before = events["/repro/plan/size_traced"]
+        for rate, slo in ((150.0, 0.3), (200.0, 0.25), (275.0, 0.4)):
+            capacity.plan_capacity(p4, rate, slo,
+                                   cluster=ClusterSpec(result_cache=cache))
+        assert events["/repro/plan/size_traced"] - before <= 1
+
+
+def test_max_rate_under_slo_composes_under_jit_and_vmap():
+    p4 = capacity.scenario("memory+cpus+disks")
+    slos = jnp.asarray([0.25, 0.3, 0.4])
+    one = [float(capacity.max_rate_under_slo(p4, float(s))) for s in slos]
+    mapped = jax.vmap(lambda s: capacity.max_rate_under_slo(p4, s))(slos)
+    np.testing.assert_allclose(np.asarray(mapped), one, rtol=1e-6)
+    cached = jax.jit(lambda prm, s: capacity.max_rate_under_slo(
+        prm, s, result_cache=S6_CACHE))(p4, 0.3)
+    assert np.isclose(float(cached), float(capacity.max_rate_under_slo(
+        p4, 0.3, result_cache=S6_CACHE)), rtol=1e-6)
+
+
+def _float64_sizing(prm, slo, cache):
+    """Section 6 sizing in float64: the Eq 7/8 (lower, upper) bounds as a
+    function of the rate, the server's service time, and the 60-step
+    bisection's largest rate whose upper bound meets the SLO (0 where
+    none does)."""
+    s = prm.hit * prm.s_hit + (1 - prm.hit) * (prm.s_miss + prm.s_disk)
+    sb = float(prm.s_broker)
+    hp = sum(1.0 / i for i in range(1, int(prm.p) + 1))
+
+    def mm1(lam, st):
+        return st / (1 - lam * st) if lam * st < 1 else math.inf
+
+    def bounds(lam):
+        lo = mm1(lam, s) + mm1(lam, sb)
+        hi = hp * mm1(lam, s) + mm1(lam, sb)
+        if cache is not None:
+            hit_r, s_cache = cache
+            hi = hi * (1 - hit_r) + mm1(lam, s_cache) * hit_r
+        return lo, hi
+
+    a, b = 0.0, min(1 / s, 1 / sb) * (1 - 1e-6)
+    for _ in range(60):
+        mid = 0.5 * (a + b)
+        a, b = (mid, b) if bounds(mid)[1] <= slo else (a, mid)
+    return bounds, s, (a if bounds(1e-6)[1] <= slo else 0.0)
+
+
+@pytest.mark.parametrize("k", [0, 1])
+@pytest.mark.parametrize("cache", [None, S6_CACHE], ids=["eq7", "eq8"])
+@pytest.mark.parametrize("name", SCENARIOS)
+def test_sizing_matches_float64_bisection(name, cache, k):
+    """The compiled sizing answers as a float64 bisection does.
+
+    float32 resolves the upper bound to about 1e-6 of itself, which is a
+    band of rates as wide as the bound is flat where it crosses the SLO:
+    the replica count may take any value that band gives (one value, but
+    where the bound at rate 0 is within a hair of the SLO).  The rest is
+    compared at the plan's own survivor rate.  An infeasible SLO keeps
+    the replica count that float32 saturates to."""
+    prm = capacity.scenario(name)
+    for slo in (0.001, 0.2, 0.3, 0.5):
+        bounds, s, per = _float64_sizing(prm, slo, cache)
+        for rate in (50.0, 123.0, 200.0, 300.0):
+            plan = capacity.plan_capacity(
+                prm, rate, slo, cluster=ClusterSpec(result_cache=cache),
+                survive_faults=k)
+            if per == 0.0:                     # no rate meets the SLO
+                assert plan.n_replicas == 2**31 - 1 + k
+                continue
+            flat = slo * 1e-3 / (bounds(per * 1.001)[1] - slo)
+            band = 1e-6 * max(flat, 1.0)
+            n = plan.n_replicas - k
+            assert (math.ceil(rate / (per * (1 + band))) <= n
+                    <= math.ceil(rate / (per * (1 - band)))), (slo, rate)
+            survivor = rate / n
+            lo, hi = bounds(survivor)
+            np.testing.assert_allclose(
+                [plan.per_replica_rate_qps, plan.response_lower_ms,
+                 plan.response_upper_ms, plan.utilization],
+                [survivor, lo * 1e3, hi * 1e3, survivor * s], rtol=1e-5,
+                err_msg=str((slo, rate)))

@@ -4,9 +4,10 @@
   that encloses its sizing, simulation dispatch and result read, in that
   order, on the calling thread; a traced ``plan_over_grid`` holds one
   ``repro.sweep.dispatch`` per (p, r) batch, annotated with both;
-* trace counters: ``/repro/plan/size_traced`` fires once per eager
-  ``plan_capacity`` call (its bisection is traced anew each time) and
-  ``/repro/stream/traced`` only when the stream engine is traced;
+* trace counters: ``/repro/plan/size_traced`` fires when the sizing
+  program of ``plan_capacity`` is traced, once per input structure and
+  not again for other rates, SLOs or keys, and ``/repro/stream/traced``
+  only when the stream engine is traced;
 * device scopes: the stream engine's lowered text names every
   ``stream.<stage>`` that its options turn on.
 
@@ -49,21 +50,6 @@ def _host_spans(trace_dir) -> list:
                          dict(e.stats)) for e in line.events
                         if e.name.startswith("repro.")]
     return out
-
-
-@pytest.fixture
-def events():
-    """``jax.monitoring`` events recorded while the test runs."""
-    seen = collections.Counter()
-    live = [True]
-
-    def listen(event, *_, **__):
-        if live[0]:
-            seen[event] += 1
-
-    jax.monitoring.register_event_listener(listen)
-    yield seen
-    live[0] = False
 
 
 def test_span_and_count_helpers(events):
@@ -122,11 +108,18 @@ def test_plan_over_grid_one_dispatch_span_per_batch(tmp_path):
 
 
 def test_size_traced_once_per_plan_call(events):
-    for seed in range(3):
-        capacity.plan_capacity(PARAMS, 200.0, 0.3, simulate=True,
+    def plan(rate, slo, seed):
+        capacity.plan_capacity(PARAMS, rate, slo, simulate=True,
                                key=jax.random.PRNGKey(seed),
                                n_queries=2000)
-    assert events["/repro/plan/size_traced"] == 3
+
+    plan(200.0, 0.3, 0)
+    first = events["/repro/plan/size_traced"]
+    for rate, slo, seed in ((190.0, 0.31, 1), (210.0, 0.32, 2),
+                            (180.0, 0.29, 3)):
+        plan(rate, slo, seed)
+    assert first <= 1
+    assert events["/repro/plan/size_traced"] == first
 
 
 def test_stream_traced_only_when_the_engine_is_traced(events):
