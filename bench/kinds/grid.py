@@ -29,6 +29,8 @@ from calls import Record, key_seeds
 from check import CONTROL_DTYPE, max_rel
 from reference import deployment, frontier, grid
 
+# work is scenarios x queries a call: simulated queries per second
+FAMILY = "grid"
 TRAFFIC = {"lam", "cpu", "disk", "hit", "r", "slo_s", "quantile",
            "n_queries", "check"}
 CHECK = {"calls", "scenarios"}

@@ -26,6 +26,8 @@ from calls import Record, key_seeds
 from check import CONTROL_DTYPE, max_rel
 from reference import deployment, whatif
 
+# the window's calls are whole planning calls, one answer each
+FAMILY = "whatif"
 TRAFFIC = {"rates", "slo_s", "n_queries", "check"}
 CHECK = {"calls"}
 CONFIG = {"p", "table_ms", "broker_fit_ms", "scenario", "routing",

@@ -4,8 +4,10 @@ chips), in a what-if cell."""
 
 import trace_reduce
 
+FAMILY = "whatif"
+
 
 def read(w):
-    if w.kind != "whatif" or w.trace is None:
+    if w.family != FAMILY or w.trace is None:
         return None
     return trace_reduce.idle_percent(w.trace_devices(), w.trace["window"])

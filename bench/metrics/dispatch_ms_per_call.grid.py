@@ -8,9 +8,10 @@ program (on a mesh, the ``shard_map`` wrapper built per dispatch)."""
 import program_spans
 
 SPAN = "repro.sweep.dispatch"
+FAMILY = "grid"
 
 
 def read(w):
-    if w.kind != "grid" or w.trace is None:
+    if w.family != FAMILY or w.trace is None:
         return None
     return program_spans.ms_per_traced_call(w, SPAN)

@@ -5,9 +5,11 @@ engine costs the device per unit of planning work."""
 
 import trace_reduce
 
+FAMILY = "grid"
+
 
 def read(w):
-    if w.kind != "grid" or w.trace is None:
+    if w.family != FAMILY or w.trace is None:
         return None
     win = w.trace["window"]
     busy_ns = sum(trace_reduce.busy_ns(d, win) for d in w.trace_devices())

@@ -9,9 +9,10 @@ import program_spans
 
 EVENT = "/repro/stream/traced"
 SPAN = "repro.grid"
+FAMILY = "grid"
 
 
 def read(w):
-    if w.kind != "grid":
+    if w.family != FAMILY:
         return None
     return program_spans.per_call(w, EVENT, SPAN)

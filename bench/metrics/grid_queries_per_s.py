@@ -2,8 +2,10 @@
 window (scenarios x queries per call), over the window's wall time, first
 call's start to last call's end."""
 
+FAMILY = "grid"
+
 
 def read(w):
-    if w.kind != "grid":
+    if w.family != FAMILY:
         return None
     return w.n_calls * w.work / w.window_s

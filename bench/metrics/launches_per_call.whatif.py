@@ -5,9 +5,11 @@ around its one simulation, and every eager op is a launch of its own."""
 
 import trace_reduce
 
+FAMILY = "whatif"
+
 
 def read(w):
-    if w.kind != "whatif" or w.trace is None:
+    if w.family != FAMILY or w.trace is None:
         return None
     win = w.trace["window"]
     n = sum(trace_reduce.executions(d, win) for d in w.trace_devices())

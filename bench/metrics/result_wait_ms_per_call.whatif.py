@@ -8,9 +8,10 @@ device."""
 import program_spans
 
 SPAN = "repro.plan.read"
+FAMILY = "whatif"
 
 
 def read(w):
-    if w.kind != "whatif" or w.trace is None:
+    if w.family != FAMILY or w.trace is None:
         return None
     return program_spans.ms_per_traced_call(w, SPAN)

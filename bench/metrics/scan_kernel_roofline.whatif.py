@@ -10,6 +10,11 @@ reads its arrival-plus-service and its service and writes its completion,
 three float32 values.  The scan does two float32 operations per element,
 so the bytes, not the operations, bound it.  The kernel's time is the
 summed device time of the Pallas kernels' operations in the window.
+
+It reads calls of the kind ``whatif`` alone, not every call of the
+what-if family: it counts the bytes of exactly one simulation of
+``w.work`` queries a call, and a call of another kind that runs several
+simulations (a loop that grows the fleet) would read low.
 """
 
 import trace_reduce

@@ -7,9 +7,10 @@ their reads to the host, up to the simulation."""
 import program_spans
 
 SPAN = "repro.plan.size"
+FAMILY = "whatif"
 
 
 def read(w):
-    if w.kind != "whatif" or w.trace is None:
+    if w.family != FAMILY or w.trace is None:
         return None
     return program_spans.ms_per_traced_call(w, SPAN)

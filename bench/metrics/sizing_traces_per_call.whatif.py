@@ -9,9 +9,10 @@ import program_spans
 
 EVENT = "/repro/plan/size_traced"
 SPAN = "repro.plan"
+FAMILY = "whatif"
 
 
 def read(w):
-    if w.kind != "whatif":
+    if w.family != FAMILY:
         return None
     return program_spans.per_call(w, EVENT, SPAN)

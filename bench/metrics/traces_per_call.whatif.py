@@ -5,9 +5,10 @@ Section 6 bisection as an eager ``lax.scan`` over a function it builds on
 every call, so every call traces again."""
 
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+FAMILY = "whatif"
 
 
 def read(w):
-    if w.kind != "whatif":
+    if w.family != FAMILY:
         return None
     return w.counters.get(TRACE_EVENT, 0) / w.n_calls

@@ -4,8 +4,10 @@ statistics)."""
 
 import numpy as np
 
+FAMILY = "whatif"
+
 
 def read(w):
-    if w.kind != "whatif":
+    if w.family != FAMILY:
         return None
     return float(np.percentile(w.latencies, 90))

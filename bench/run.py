@@ -7,7 +7,8 @@ A cell is a ``workloads`` entry of ``BENCHMARK.json``: a configuration
 (``configs/<config>.json``) under a traffic mix (``traffic/<traffic>.json``,
 read by the generator of its kind, ``kinds/<kind>.py``; see ``calls.py``).
 Its limits are ``limits/<cell>.json``, and each metric is read by
-``metrics/<metric>.py``.  A run:
+``metrics/<metric>.py`` (most read the windows of one family of calls;
+see ``calls.py``).  A run:
 
 1. fails, printing no result, unless JAX finds a TPU with the cell's chips;
 2. keeps JAX's compilation cache in ``.jax_cache/`` at the checkout's root;
@@ -36,13 +37,14 @@ T_CHIP = T_START        # when the chip was found (main sets it)
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+
+import calls as calls_mod  # noqa: E402
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -70,6 +72,11 @@ class Window:
     config: dict
     trace: dict = None          # trace_reduce.load(...) with --trace 1
     n_traced: int = 0           # the window's last calls, which it holds
+    family: str = None          # the kind's FAMILY; the kind where not given
+
+    def __post_init__(self):
+        if self.family is None:
+            self.family = self.kind
 
     @property
     def n_calls(self) -> int:
@@ -93,12 +100,8 @@ def load_json(path: pathlib.Path) -> dict:
 
 
 def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return calls_mod.load_module(calls_mod.METRICS / f"{name}.py",
+                                 "bench_metric_").read
 
 
 def metrics_of(bench: dict, section: str, cell: str) -> list:
@@ -144,7 +147,6 @@ def peak_bytes(devices) -> int:
 def run(args, bench: dict, cell: dict, devices) -> dict:
     """Set up, measure and check one cell; the result line's object."""
     import jax
-    import calls as calls_mod
     import check
     import trace_reduce
 
@@ -207,10 +209,11 @@ def run(args, bench: dict, cell: dict, devices) -> dict:
         shutil.rmtree(trace_dir, ignore_errors=True)
     memory = peak_bytes(devices)
 
-    window = Window(kind=calls.kind, chips=cell["chips"], setup_s=setup_s,
-                    work=calls.work, starts=starts, ends=ends,
-                    counters=counters, peaks=peaks[kind], config=config,
-                    trace=reduced, n_traced=n_traced)
+    window = Window(kind=calls.kind, family=calls.family,
+                    chips=cell["chips"], setup_s=setup_s, work=calls.work,
+                    starts=starts, ends=ends, counters=counters,
+                    peaks=peaks[kind], config=config, trace=reduced,
+                    n_traced=n_traced)
     failed = sum(calls.failed(rec) for rec in records)
     numbers = calls.numbers(records, args.seed, window.latencies)
     correct, table = check.judge(numbers, check.limits(cell["name"]))

@@ -4,13 +4,16 @@ timed path broken underneath: ``correct`` has to come out false.
 The faults a planning cell can have: an answer altered where it is
 produced; half of the batch left out and the mean taken over the rest;
 and, for a grid sharded over four chips, the exchange between chips left
-out.  A sound
-run of the same cells comes out correct.
+out.  Every one-chip cell of ``BENCHMARK.json`` is run sound and with
+the faults of its kind's family; a sound run comes out correct.  Last, a
+new kind of call in new files alone reports its family's metrics.
 """
 
 import argparse
 import dataclasses
+import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -18,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import calls as calls_mod
+import check
 import run as bench_run
 import tiny
 from repro.core import simulator
@@ -71,24 +76,36 @@ def half_batch_whatif(fn):
     return wrapper
 
 
-@pytest.mark.parametrize("name", ["t6.grid", "t6.whatif", "s6jsq.whatif"])
+# the simulator entry each family's calls go through, and its faults
+FAULTS = {"whatif": ("simulate_fork_join", (altered, half_batch_whatif)),
+          "grid": ("simulate_fork_join_batch", (altered, half_batch_grid))}
+ONE_CHIP = [w for w in tiny.bench()["workloads"] if w["chips"] == 1]
+
+
+def fault_cases():
+    """(cell, simulator entry, fault) for every one-chip cell and each
+    fault of its family; a family with none known is a case that fails."""
+    for cell in ONE_CHIP:
+        family = tiny.family(cell)
+        target, faults = FAULTS.get(family, (None, (None,)))
+        for fault in faults:
+            yield pytest.param(
+                cell["name"], target, fault,
+                id=f"{cell['name']}-{getattr(fault, '__name__', family)}")
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in ONE_CHIP])
 def test_sound_run_is_correct(name, monkeypatch, tmp_path):
     out = run_cell(name, monkeypatch, tmp_path)
     assert out["correct"], out["check"]
     assert list(out)[-1] == "check"
-    assert {"setup_s"} <= set(out["metrics"])
+    assert {"setup_s"} < set(out["metrics"])
     assert out["device"]["count"] == 1
 
 
-@pytest.mark.parametrize("name,target,fault", [
-    ("t6.grid", "simulate_fork_join_batch", altered),
-    ("t6.grid", "simulate_fork_join_batch", half_batch_grid),
-    ("t6.whatif", "simulate_fork_join", altered),
-    ("t6.whatif", "simulate_fork_join", half_batch_whatif),
-    ("s6jsq.whatif", "simulate_fork_join", altered),
-    ("s6jsq.whatif", "simulate_fork_join", half_batch_whatif),
-])
+@pytest.mark.parametrize("name,target,fault", list(fault_cases()))
 def test_fault_is_caught(name, target, fault, monkeypatch, tmp_path):
+    assert fault is not None, f"no faults known for the family of {name}"
     monkeypatch.setattr(simulator, target,
                         fault(getattr(simulator, target)))
     out = run_cell(name, monkeypatch, tmp_path)
@@ -159,3 +176,54 @@ def test_traced_run_reports_the_layers(monkeypatch, tmp_path):
     for entries in out["breakdown"].values():
         assert 0 < len(entries) <= 10
     assert list(out)[-1] == "check"
+
+
+def test_a_new_kind_is_new_files_alone(monkeypatch, tmp_path):
+    """The promise of ``calls.py``: a new kind of call of a known family
+    needs only new files (here a copy of the what-if generator, a traffic
+    file with its ``tiny`` object and limits) to report the family's
+    end-to-end metrics, and comes out correct; a kind without a
+    ``FAMILY``, or of a family no end-to-end metric reads, is refused."""
+    kinds = tmp_path / "kinds"
+    shutil.copytree(tiny.BENCH / "kinds", kinds,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(kinds / "whatif.py", kinds / "whatif_copy.py")
+    for sub in ("traffic", "limits"):
+        (tmp_path / sub).mkdir()
+    shutil.copy(tiny.BENCH / "peaks.json", tmp_path)
+    traffic = tiny.load(tiny.BENCH / "traffic" / "whatif_50to300.json")
+    traffic["kind"] = "whatif_copy"
+    (tmp_path / "traffic" / "whatif_copy.json").write_text(
+        json.dumps(traffic))
+    shutil.copy(tiny.BENCH / "limits" / "t6.whatif.json",
+                tmp_path / "limits" / "t6.whatif_copy.json")
+    monkeypatch.setattr(calls_mod, "KINDS", kinds)
+    monkeypatch.setattr(bench_run, "BENCH", tmp_path)
+    monkeypatch.setattr(check, "HERE", tmp_path)
+
+    bench = tiny.bench()
+    cell = dict(tiny.cell("t6.whatif"), name="t6.whatif_copy",
+                traffic="whatif_copy")
+    bench["workloads"].append(cell)
+    for m in bench["end_to_end"]:
+        if "t6.whatif" in m.get("workloads", []):
+            m["workloads"].append(cell["name"])
+    monkeypatch.setattr(bench_run, "load_json", tiny.load_shrunk)
+    monkeypatch.setattr(bench_run, "CACHE_DIR", tmp_path / "cache")
+    args = argparse.Namespace(workload=cell["name"], seed=2**31 + 5,
+                              seconds=0.5, trace=0)
+    out = bench_run.run(args, bench, cell, [FakeChip()])
+    assert out["correct"], out["check"]
+    assert set(out["metrics"]) == {"whatif_mean_s", "whatif_p90_s",
+                                   "setup_s"}
+
+    source = (kinds / "whatif.py").read_text()
+    traffic = tiny.shrink(tmp_path / "traffic" / "whatif_copy.json")
+    for kind, family, refusal in [("nofamily", "", "declares no FAMILY"),
+                                  ("unread", 'FAMILY = "nosuch"\n',
+                                   "reads its family 'nosuch'")]:
+        (kinds / f"{kind}.py").write_text(
+            source.replace('FAMILY = "whatif"\n', family))
+        with pytest.raises(ValueError, match=f"'{kind}'.*{refusal}"):
+            calls_mod.make(tiny.config(cell), dict(traffic, kind=kind), 1,
+                           7)

@@ -20,12 +20,12 @@ PEAKS = json.loads((bench_run.BENCH / "peaks.json").read_text())["devices"]
 
 
 def window(kind, starts, ends, trace=None, counters=None, chips=1,
-           config=None, work=1000.0, setup_s=12.5, n_traced=0):
+           config=None, work=1000.0, setup_s=12.5, n_traced=0, family=None):
     return bench_run.Window(
         kind=kind, chips=chips, setup_s=setup_s, work=work, starts=starts,
         ends=ends, counters=counters or {}, peaks=PEAKS["TPU v5 lite"],
         config=config or {"p": 100, "result_cache": None}, trace=trace,
-        n_traced=n_traced)
+        n_traced=n_traced, family=family)
 
 
 def read(name, w):
@@ -44,6 +44,23 @@ def test_end_to_end_metrics():
     assert read("whatif_p90_s", w) == pytest.approx(np.percentile(lat, 90))
     assert read("setup_s", w) == 12.5
     assert read("grid_queries_per_s", w) is None
+
+
+def test_end_to_end_metrics_follow_the_family():
+    """Another kind of call of the what-if family reads what a what-if
+    call reads; a kind of the grid family reads no what-if metric."""
+    starts = list(np.arange(10.0))
+    ends = list(np.arange(10.0) + 0.5 + 0.01 * np.arange(10))
+    w = window("whatif_n1", starts, ends, family="whatif")
+    assert w.kind == "whatif_n1"
+    for name in ("whatif_mean_s", "whatif_p90_s"):
+        assert read(name, w) == read(name, window("whatif", starts, ends))
+    assert read("grid_queries_per_s", w) is None
+    g = window("grid_n1", starts, ends, family="grid", work=2048 * 20000)
+    assert read("whatif_mean_s", g) is None
+    assert read("whatif_p90_s", g) is None
+    assert read("grid_queries_per_s", g) == pytest.approx(
+        10 * 2048 * 20000 / ends[-1])
 
 
 def test_traces_per_call():
@@ -117,3 +134,22 @@ def test_per_layer_metrics_on_recorded_trace(recorded):
                work=1e6, n_traced=n_calls)
     assert read("engine_busy_ms_per_mquery.grid", g) == pytest.approx(
         busy / 1e6 / n_calls)
+
+
+def test_per_layer_metrics_follow_the_family(recorded):
+    """On the recorded trace, another kind of the what-if family reads
+    every what-if per-layer metric as the what-if kind does, except the
+    scan kernel's roofline, which counts one simulation a call and so
+    reads the kind ``whatif`` alone."""
+    n_calls = recorded["calls"]
+    args = ([0.0] * n_calls, [1.0] * n_calls)
+    kw = dict(trace=recorded, work=60000.0, n_traced=n_calls,
+              counters={"/jax/core/compile/jaxpr_trace_duration": 4})
+    same = window("whatif", *args, **kw)
+    other = window("whatif_n1", *args, family="whatif", **kw)
+    for name in ("launches_per_call.whatif", "device_idle.whatif",
+                 "traces_per_call.whatif"):
+        assert read(name, same) is not None
+        assert read(name, other) == read(name, same)
+    assert read("scan_kernel_roofline.whatif", same) is not None
+    assert read("scan_kernel_roofline.whatif", other) is None

@@ -24,14 +24,14 @@ MS = 1_000_000  # ns
 
 
 def window(kind, host, counters=None, n_calls=6, n_traced=2,
-           span=(10 * MS, 400 * MS)):
+           span=(10 * MS, 400 * MS), family=None):
     trace = {"window": list(span), "devices": [], "host": host}
     return bench_run.Window(
         kind=kind, chips=1, setup_s=1.0, work=1000.0,
         starts=[0.0] * n_calls, ends=[1.0] * n_calls,
         counters=counters or {}, peaks=PEAKS["TPU v5 lite"],
         config={"p": 100, "result_cache": None}, trace=trace,
-        n_traced=n_traced)
+        n_traced=n_traced, family=family)
 
 
 def read(name, w):
@@ -97,6 +97,17 @@ def test_nothing_in_the_other_kind_or_without_spans(name):
     untraced = window("whatif" if whatif else "grid", [])
     untraced.trace = None
     assert read(name, untraced) is None
+
+
+@pytest.mark.parametrize("name", WHATIF_SPANS + WHATIF_COUNTERS + GRID)
+def test_another_kind_of_the_family_reads_the_same(name):
+    family = name.rsplit(".", 1)[1]
+    host = WHATIF_HOST if family == "whatif" else GRID_HOST
+    counters = {"/repro/plan/size_traced": 6, "/repro/stream/traced": 6}
+    want = read(name, window(family, host, counters=counters))
+    assert want is not None
+    assert read(name, window(family + "_n1", host, counters=counters,
+                             family=family)) == want
 
 
 @pytest.fixture(scope="module")

@@ -5,6 +5,7 @@ benchmark compares; the control (the reference's simulation in bfloat16)
 fails them; the frontier arithmetic agrees with ``chip_smoke.py``'s.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -156,3 +157,12 @@ def test_unknown_kind_or_routing_is_refused(kind, routing):
         config["routing"] = routing
     with pytest.raises(ValueError, match=routing or kind):
         calls_mod.make(config, traffic, 1, SEED)
+
+
+def test_traffic_without_tiny_is_refused_by_name(tmp_path):
+    """A traffic file the CPU tests cannot shrink fails its tests with a
+    message that names the file and the missing ``tiny`` object."""
+    path = tmp_path / "mix.json"
+    path.write_text(json.dumps({"kind": "whatif", "check": {"calls": 8}}))
+    with pytest.raises(ValueError, match=r"mix\.json has no 'tiny' object"):
+        tiny.shrink(path)

@@ -1,22 +1,18 @@
 """Tiny versions of the benchmark's traffic mixes, for the CPU tests.
 
 Every cell keeps its configuration, routing and kind of call; only the
-grid's axes, the rate sets and the simulated query counts shrink.
+sizes that its traffic file's own ``tiny`` object gives (the grid's
+axes, the rate sets, the simulated query counts) and the checked sample
+shrink.
 """
 
-import copy
 import json
 import pathlib
 
+import calls
+
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
-
-SIZES = {
-    "grid16": dict(n_queries=2000, lam={"linspace": [10.0, 160.0, 2]},
-                   cpu=[1.0, 4.0], disk=[4.0], hit=[0.02, 0.18]),
-    "whatif_50to300": dict(n_queries=3000, rates=[50.0, 150.0, 300.0]),
-    "whatif_100to300": dict(n_queries=3000, rates=[100.0, 250.0]),
-}
 
 
 def load(path: pathlib.Path) -> dict:
@@ -43,19 +39,32 @@ def config(cell_entry: dict) -> dict:
     return load(ROOT / conf["file"])
 
 
-def traffic(name: str) -> dict:
-    t = load(BENCH / "traffic" / f"{name}.json")
-    t = copy.deepcopy(t)
-    t.update(SIZES[name])
+def family(cell_entry: dict) -> str:
+    """The ``FAMILY`` of the kind of call that the cell's traffic names."""
+    kind = load(BENCH / "traffic" / f"{cell_entry['traffic']}.json")["kind"]
+    return getattr(calls.kind_module(kind), "FAMILY", None)
+
+
+def shrink(path: pathlib.Path) -> dict:
+    """The traffic file at ``path`` with its ``tiny`` sizes applied."""
+    t = load(path)
+    if "tiny" not in t:
+        raise ValueError(f"{path} has no 'tiny' object: the CPU tests "
+                         "cannot shrink its traffic")
+    t.update(t.pop("tiny"))
     t["check"]["calls"] = 2
     if "scenarios" in t["check"]:
         t["check"]["scenarios"] = 6
     return t
 
 
+def traffic(name: str) -> dict:
+    return shrink(BENCH / "traffic" / f"{name}.json")
+
+
 def load_shrunk(path: pathlib.Path) -> dict:
     """``run.load_json`` with every traffic file shrunk."""
     path = pathlib.Path(path)
     if path.parent.name == "traffic":
-        return traffic(path.stem)
+        return shrink(path)
     return load(path)
