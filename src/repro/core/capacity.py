@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
+import warnings
 from typing import Callable, Optional
 
 import jax
@@ -40,6 +42,8 @@ __all__ = [
 ]
 
 _MS = 1e-3
+# the largest fleet plan_capacity simulates
+_SIM_REPLICA_CAP = 256
 
 # --- Paper Table 5: validation cluster (8 servers, b = 1.25M pages) -------
 TABLE5_PARAMS = ServerParams(
@@ -185,13 +189,18 @@ class CapacityPlan:
     Sec-6 answer, which stays the provisioning headline) quantifies the
     elastic saving.
 
-    ``survive_faults``/``response_faulted_p95_ms`` are the N+k
-    survivability extension (``plan_capacity(..., survive_faults=k)``):
-    the fleet is provisioned with k spare replicas so the SLO holds
-    with k replicas down, and — when the simulated cross-check ran —
-    ``response_faulted_p95_ms`` is the observed p95 of exactly that
-    degraded scenario (k replicas held down for the whole run, failover
-    routing spilling their share to the survivors).
+    ``survive_faults``/``response_faulted_p95_ms``/
+    ``faulted_degraded_fraction`` are the N+k survivability extension
+    (``plan_capacity(..., survive_faults=k)``): the fleet is provisioned
+    with k spare replicas so the SLO holds with k replicas down, and —
+    when the simulated cross-check ran — ``response_faulted_p95_ms`` is
+    the observed p95 of exactly that degraded scenario (k replicas held
+    down for the whole run, round-robin failover splitting their share
+    evenly over the survivors) and ``faulted_degraded_fraction`` the
+    share of its queries answered on a partial quorum (the price of
+    "good enough" answers; 0 without a broker timeout).  Both describe
+    the returned ``n_replicas``, as the fault-free
+    ``response_simulated_*`` do.
     """
 
     n_replicas: int
@@ -208,6 +217,7 @@ class CapacityPlan:
     mean_active_replicas: Optional[float] = None
     survive_faults: int = 0
     response_faulted_p95_ms: Optional[float] = None
+    faulted_degraded_fraction: Optional[float] = None
 
 
 def plan_capacity(
@@ -255,13 +265,19 @@ def plan_capacity(
     (n - k)`` and ``n`` gains k spares, so the plan is always at least
     as conservative as the fault-free one (equal at k=0).  With
     ``simulate=True`` the cross-check runs exactly that degraded
-    scenario — k replicas held down for the whole run via a
-    `repro.core.faults.FaultSpec` outage window, failover spilling
-    their share to survivors — and if the observed p95 still misses
-    the SLO (routing imbalance the even-split bound can't see), the
-    fleet is grown further until it holds.  The plan only accepts a
-    configuration whose simulated faulted p95 meets the SLO
-    (``response_faulted_p95_ms``).
+    scenario — replicas 0..k-1 held down for the whole run via
+    `repro.core.faults.FaultSpec` outage windows, failover moving their
+    share to the survivors — and if the observed p95 still misses the
+    SLO (imbalance or tails the even-split bound can't see), the fleet
+    is grown by 1, 2, 4, ... replicas until it holds and then bisected
+    down to the smallest fleet that holds (the p95 falls as the fleet
+    grows), or stops at the simulator's 256-replica cap, which warns
+    (``response_faulted_p95_ms``).  A ``ClusterSpec.fault`` with no
+    outages of its own (a broker timeout and quorum, hedging, degraded
+    servers) is run by both simulations, the outages added to it; one
+    with ``outages`` or ``mtbf_seconds`` is refused.  The fault-free
+    cross-check runs after the fleet is settled, so every simulated
+    number of the plan describes the returned ``n_replicas``.
     """
     spec = resolve_cluster(cluster, routing=routing,
                            result_cache=result_cache,
@@ -282,11 +298,11 @@ def plan_capacity(
             "survive_faults sizes a static fleet; with an autoscale "
             "policy the max_r provisioning is the policy's job — plan "
             "the two separately")
-    if k_down and spec.fault is not None:
+    if k_down and spec.fault is not None and spec.fault.has_outages:
         raise ValueError(
-            "survive_faults synthesizes its own k-replicas-down "
-            "FaultSpec; a ClusterSpec.fault would double-inject — give "
-            "one or the other")
+            "survive_faults adds its own k-replicas-down outages to the "
+            "ClusterSpec.fault; a fault spec with outages or an MTBF "
+            "process would double-inject — give one or the other")
     with span("plan"):
         return _plan(params, target_rate, slo_seconds, spec, simulate,
                      key, n_queries, mode, k_down)
@@ -307,6 +323,77 @@ def _size(params, target_rate, slo_seconds, cache):
     return n, per_replica, lo, hi, util
 
 
+@jax.jit
+def _read(sim):
+    """The numbers a plan reads off a simulation, as one program: its mean
+    and 95th percentile, and where the run had them the share answered on
+    a partial quorum and the mean active replica count."""
+    out = {"mean": sim.mean_response, "p95": sim.quantile(0.95)}
+    if sim.degraded_count is not None:
+        out["degraded"] = sim.degraded_fraction
+    if sim.replica_seconds is not None:
+        out["mean_active"] = sim.mean_active_replicas
+    return out
+
+
+def _grow(holds, n0: int, cap: int) -> int:
+    """The smallest fleet from ``n0`` up for which ``holds(n)``, taken to
+    stay true as n grows: fleets n0, n0 + 1, n0 + 2, n0 + 4, ... until one
+    holds, then a bisection between the last that did not and it, so
+    O(log n) calls of ``holds``.  Where no fleet below ``cap`` holds, cap,
+    whether or not it holds."""
+    if holds(n0):
+        return n0
+    lo, step = n0, 1
+    while lo < cap:
+        hi = min(n0 + step, cap)
+        if holds(hi):
+            while hi - lo > 1:
+                mid = (lo + hi) // 2
+                if holds(mid):
+                    hi = mid
+                else:
+                    lo = mid
+            return hi
+        lo, step = hi, 2 * step
+    return lo
+
+
+def _survive(key, params, target_rate, slo_seconds, spec, n_queries, mode,
+             k_down, n_i):
+    """The N+k check: replicas 0..k-1 down for the whole run (the
+    peak-coincident worst case) on top of the spec's own timeout, quorum,
+    hedging and degraded servers.  The even-split bound already sized for
+    this; the simulation also sees the tails it cannot, so where the
+    faulted p95 misses the SLO the fleet grows (`_grow`, one faulted
+    simulation per fleet size it tries) up to the simulation cap, which
+    warns.  Returns ``(n, numbers)``, the numbers of ``_read`` of the
+    faulted run at n."""
+    from repro.core import simulator  # deferred: planner-only dep
+    down = dataclasses.replace(
+        spec.fault or FaultSpec(),
+        outages=tuple((j, 0.0, math.inf) for j in range(k_down)))
+    runs = {}
+
+    def holds(n):
+        ft = simulator.simulate_fork_join(
+            key, float(target_rate), n_queries, params, mode=mode,
+            cluster=dataclasses.replace(spec, r=n, fault=down))
+        count("plan/faulted_sim")
+        with span("plan.faults.read"):
+            runs[n] = jax.device_get(_read(ft))
+        return float(runs[n]["p95"]) <= slo_seconds
+
+    n = _grow(holds, n_i, _SIM_REPLICA_CAP)
+    if float(runs[n]["p95"]) > slo_seconds:
+        warnings.warn(
+            f"the N+{k_down} fleet stopped growing at the "
+            f"{_SIM_REPLICA_CAP}-replica simulation cap with the faulted "
+            f"p95 at {float(runs[n]['p95']) * 1e3:.1f} ms, above the "
+            f"{slo_seconds * 1e3:g} ms SLO", UserWarning, stacklevel=4)
+    return n, runs[n]
+
+
 def _plan(params, target_rate, slo_seconds, spec, simulate, key,
           n_queries, mode, k_down) -> CapacityPlan:
     """`plan_capacity` after its arguments are checked: the sizing, then
@@ -323,13 +410,19 @@ def _plan(params, target_rate, slo_seconds, spec, simulate, key,
         upper_ms, lower_ms = float(hi) * 1e3, float(lo) * 1e3
         util = float(util)
         per_replica = float(per_replica)
-    sim_ms = sim_p95_ms = mean_active = faulted_p95_ms = None
-    _SIM_REPLICA_CAP = 256
+    sim_ms = sim_p95_ms = mean_active = faulted_p95_ms = degraded = None
     sim_r = (spec.autoscale.max_r if spec.autoscale is not None else n_i)
     feasible = per_replica > 1e-9 or spec.autoscale is not None
     if simulate and feasible and sim_r <= _SIM_REPLICA_CAP:
         from repro.core import simulator  # deferred: planner-only dep
         key = jax.random.PRNGKey(0) if key is None else key
+        if k_down:
+            with span("plan.faults"):
+                n_i, faulted = _survive(
+                    key, params, target_rate, slo_seconds, spec, n_queries,
+                    mode, k_down, n_i)
+            faulted_p95_ms = float(faulted["p95"]) * 1e3
+            degraded = float(faulted["degraded"])
         sim_spec = (spec if spec.autoscale is not None
                     else dataclasses.replace(spec, r=n_i))
         with span("plan.simulate"):
@@ -337,32 +430,12 @@ def _plan(params, target_rate, slo_seconds, spec, simulate, key,
                 key, float(target_rate), n_queries, params, mode=mode,
                 cluster=sim_spec)
         with span("plan.read"):
-            sim_ms = float(sim.mean_response) * 1e3
-            sim_p95_ms = float(sim.quantile(0.95)) * 1e3
+            got = jax.device_get(_read(sim))
+            sim_ms = float(got["mean"]) * 1e3
+            sim_p95_ms = float(got["p95"]) * 1e3
             if spec.autoscale is not None:
-                mean_active = float(sim.mean_active_replicas)
-        if k_down:
-            # the survivability check proper: k replicas held down for
-            # the WHOLE run (the peak-coincident worst case), failover
-            # spilling their share to the survivors.  The even-split
-            # bound already sized for this; the simulation additionally
-            # sees routing imbalance, so grow the fleet if p95 misses.
-            horizon = 2.0 * n_queries / max(float(target_rate), 1e-9)
-            down = FaultSpec(
-                outages=tuple((j, 0.0, horizon) for j in range(k_down)))
-            with span("plan.faults"):
-                for _ in range(4):
-                    ft_spec = dataclasses.replace(spec, r=n_i, fault=down)
-                    ft = simulator.simulate_fork_join(
-                        key, float(target_rate), n_queries, params,
-                        mode=mode, cluster=ft_spec)
-                    faulted_p95_ms = float(ft.quantile(0.95)) * 1e3
-                    if (faulted_p95_ms <= slo_seconds * 1e3
-                            or n_i >= _SIM_REPLICA_CAP):
-                        break
-                    n_i += 1
+                mean_active = float(got["mean_active"])
     elif simulate:
-        import warnings
         reason = ("infeasible SLO" if per_replica <= 1e-9
                   else f"above the {_SIM_REPLICA_CAP}-replica simulation "
                        "cap")
@@ -386,6 +459,7 @@ def _plan(params, target_rate, slo_seconds, spec, simulate, key,
         mean_active_replicas=mean_active,
         survive_faults=k_down,
         response_faulted_p95_ms=faulted_p95_ms,
+        faulted_degraded_fraction=degraded,
     )
 
 

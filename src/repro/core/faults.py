@@ -22,9 +22,11 @@ Four orthogonal failure channels:
   ``mttr_seconds``: per query step of length dt an up replica fails
   w.p. 1 - exp(-dt/MTBF), a down one repairs w.p. 1 - exp(-dt/MTTR) —
   memoryless, so the process is exact for any interarrival spacing).
-  Down replicas receive no new queries: oblivious policies spill to the
-  next surviving replica, JSQ masks them out of the argmin, and
-  in-flight work keeps draining (same semantics as autoscale scale-in).
+  Down replicas receive no new queries: round-robin splits the stream
+  evenly over the survivors (query g to the (g mod n_up)-th surviving
+  replica in index order), random routing spills to the next surviving
+  replica, JSQ masks them out of the argmin, and in-flight work keeps
+  draining (same semantics as autoscale scale-in).
 * **Degraded servers** — ``degraded``: tuples of ``(server, factor)``
   multiplying that server column's service times on every replica (a
   slow disk or thermally throttled CPU on one index partition; the

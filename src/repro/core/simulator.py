@@ -516,15 +516,19 @@ def _routing_assign(routing: str, r: int, key: Array, c_idx, gidx,
     n_active — so inactive replicas receive no new work and drain.
 
     ``up`` (fault injection): per-query replica-up mask (S, chunk, r)
-    from `repro.core.faults.fault_scan`.  Failover spills a query
-    raw-routed to a down replica onto the next surviving (and active)
-    replica cyclically — the smallest offset j with up[(raw + j) % r] —
-    which preserves round-robin's even split over the survivors.
-    ``spill`` marks re-routed queries, ``unavail`` queries for which no
-    active replica was up (those keep their raw assignment: the
-    dispatcher has nowhere better to send them, and the availability
-    channel records the incident).  Both are None when ``up`` is None,
-    and the assignment is bit-identical to the fault-free one.
+    from `repro.core.faults.fault_scan`.  Round-robin fails over evenly:
+    query g goes to the (g mod n_up)-th surviving (and active) replica
+    in index order, n_up the count of those at its arrival, so the
+    survivors split the load evenly as the Section 6 sizing assumes;
+    with every replica up that is g mod r (or g mod n_active).  Random
+    routing spills a query raw-routed to a down replica onto the next
+    surviving replica cyclically — the smallest offset j with
+    up[(raw + j) % r].  ``spill`` marks queries whose raw replica was
+    down, ``unavail`` queries for which no active replica was up (those
+    keep their raw assignment: the dispatcher has nowhere better to send
+    them, and the availability channel records the incident).  Both are
+    None when ``up`` is None, and the assignment is bit-identical to the
+    fault-free one.
     """
     if routing == "round_robin":
         if n_act is not None:
@@ -546,12 +550,19 @@ def _routing_assign(routing: str, r: int, key: Array, c_idx, gidx,
     ok = up
     if n_act is not None:
         ok = ok & (jnp.arange(r)[None, None, :] < n_act[:, :, None])
-    cand = (raw[:, :, None] + jnp.arange(r)[None, None, :]) % r
-    ok_c = jnp.take_along_axis(ok, cand, axis=-1)     # (S, chunk, r)
-    j = jnp.argmax(ok_c, axis=-1).astype(jnp.int32)   # first ok offset
-    any_ok = jnp.any(ok_c, axis=-1)
-    assign = jnp.where(any_ok, (raw + j) % r, raw)
-    return assign, any_ok & (j > 0), ~any_ok
+    any_ok = jnp.any(ok, axis=-1)
+    raw_ok = jnp.take_along_axis(ok, raw[:, :, None], axis=-1)[..., 0]
+    if routing == "round_robin":
+        rank = jnp.cumsum(ok.astype(jnp.int32), axis=-1)  # (S, chunk, r)
+        nth = gidx[None, :].astype(jnp.int32) % jnp.maximum(rank[..., -1], 1)
+        pick = jnp.argmax(ok & (rank == nth[..., None] + 1), axis=-1)
+        assign = jnp.where(any_ok, pick.astype(jnp.int32), raw)
+    else:
+        cand = (raw[:, :, None] + jnp.arange(r)[None, None, :]) % r
+        ok_c = jnp.take_along_axis(ok, cand, axis=-1)     # (S, chunk, r)
+        j = jnp.argmax(ok_c, axis=-1).astype(jnp.int32)   # first ok offset
+        assign = jnp.where(any_ok, (raw + j) % r, raw)
+    return assign, any_ok & ~raw_ok, ~any_ok
 
 
 def _jsq_route(w: Array, gaps: Array, services: Array, live: Array,
@@ -1608,13 +1619,35 @@ def simulate_fork_join(
     from repro.kernels.maxplus_scan.ops import resolve_scan_impl
     impl = resolve_scan_impl(impl)  # concrete before the jit cache key
     p = int(params.p) if p is None else p  # static before tracing
-    cache_hit, cache_service, has_cache = _cache_args(spec.result_cache)
-    proc = _as_batch_process(lam)
-    _check_trace(proc, n_queries)
-    chunk = _clamp_chunk_for_profile(
-        proc, max(1, min(chunk_size, n_queries)))
-    res = _simulate_stream(key, proc, _vec_params(params), cache_hit,
-                           cache_service, n_queries, p,
+    chunk = max(1, min(chunk_size, n_queries))
+    if isinstance(lam, ArrivalProcess):
+        lam = _as_batch_process(lam)
+        _check_trace(lam, n_queries)
+        chunk = _clamp_chunk_for_profile(lam, chunk)
+    # a constant rate is a stationary process, which the clamp leaves be;
+    # the cache's values are traced, the rest of the topology static
+    return _simulate_one(key, lam, params, spec.result_cache,
+                         n_queries=n_queries, p=p, mode=mode, impl=impl,
+                         chunk=chunk, warmup_fraction=warmup_fraction,
+                         hist_bins=hist_bins, tap_size=tap_size,
+                         spec=dataclasses.replace(spec, result_cache=None),
+                         telemetry=telemetry)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_queries", "p", "mode", "impl", "chunk",
+                              "warmup_fraction", "hist_bins", "tap_size",
+                              "spec", "telemetry"))
+def _simulate_one(key, lam, params, result_cache, *, n_queries, p, mode,
+                  impl, chunk, warmup_fraction, hist_bins, tap_size, spec,
+                  telemetry):
+    """`simulate_fork_join` past its Python-level checks, as one program:
+    the inputs promoted to a batch of one scenario, the engine, and the
+    batch axis taken off the result (run eagerly, the promotions and the
+    slices are some 30 launches and transfers a call)."""
+    cache_hit, cache_service, has_cache = _cache_args(result_cache)
+    res = _simulate_stream(key, _as_batch_process(lam), _vec_params(params),
+                           cache_hit, cache_service, n_queries, p,
                            mode, impl, chunk, warmup_fraction, hist_bins,
                            tap_size, r=spec.engine_r, routing=spec.routing,
                            has_cache=has_cache,

@@ -109,6 +109,25 @@ def test_sizing_traced_at_most_once_per_structure(events):
         assert events["/repro/plan/size_traced"] - before <= 1
 
 
+def test_survive_faults_traces_engine_once_for_two_rates(events):
+    """The N+k outage lasts the whole run whatever the rate, so two rates
+    that size the same fleet reuse both simulations' programs."""
+    from repro.core.faults import FaultSpec
+    p4 = capacity.scenario("memory+cpus+disks")
+    spec = ClusterSpec(fault=FaultSpec(broker_timeout_seconds=0.15,
+                                       quorum_k=95,
+                                       hedge_after_seconds=0.21))
+    plans = []
+    for rate in (150.0, 140.0):
+        before = events["/repro/stream/traced"]
+        plans.append(capacity.plan_capacity(
+            p4, rate, 0.3, cluster=spec, simulate=True,
+            key=jax.random.PRNGKey(3), n_queries=2_000, survive_faults=1))
+        traced = events["/repro/stream/traced"] - before
+    assert plans[0].n_replicas == plans[1].n_replicas == 4
+    assert traced == 0
+
+
 def test_max_rate_under_slo_composes_under_jit_and_vmap():
     p4 = capacity.scenario("memory+cpus+disks")
     slos = jnp.asarray([0.25, 0.3, 0.4])

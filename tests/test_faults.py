@@ -12,16 +12,21 @@ Five contracts:
 * failover semantics — a replica in an outage window receives no
   queries, its share spills to the survivors (``spill_fraction`` > 0,
   ``availability`` = 1 while any replica survives; with ALL replicas
-  down arrivals are counted unavailable);
+  down arrivals are counted unavailable), and round-robin splits the
+  stream evenly over the survivors;
 * degraded operation — a broker timeout with k-of-p quorum caps the
   join, degraded responses are counted, and hedged retries can only
   help (p95 never worse than the unhedged twin on the same draws);
 * plan conservativeness — ``plan_capacity(survive_faults=k)`` never
   provisions fewer replicas than the fault-free plan and records the
-  simulated p95 of the k-down scenario.
+  simulated p95 of the k-down scenario; it runs a spec's own timeout,
+  quorum and hedging under the outages, grows the fleet until the
+  faulted p95 holds (or warns at the cap), and every simulated number
+  of the plan describes the fleet it returns.
 """
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,9 +52,9 @@ ALL_UP = FaultSpec(degraded=((0, 1.0), (2, 1.0)),
 
 
 def run(fault, *, routing="round_robin", r=3, n=4_000, rate=60.0,
-        key=KEY, **kw):
+        key=KEY, chunk_size=512, **kw):
     return simulator.simulate_fork_join(
-        key, rate, n, PARAMS, chunk_size=512,
+        key, rate, n, PARAMS, chunk_size=chunk_size,
         cluster=ClusterSpec(r=r, routing=routing, fault=fault), **kw)
 
 
@@ -92,6 +97,27 @@ def test_outage_spills_to_survivors():
     assert float(res.unavail_count) == 0.0
     # round_robin sends ~1/3 of arrivals to the dead replica's slot
     assert abs(float(res.spill_fraction) - 1.0 / 3.0) < 0.1
+
+
+@pytest.mark.parametrize("first_chunk", [0, 7])
+def test_round_robin_failover_splits_evenly(first_chunk):
+    """With replica 0 of 4 down, round-robin sends query g to the
+    (g mod 3)-th survivor: each gets a third of a block to within one
+    query, and ``spill`` marks exactly the queries whose raw replica
+    (g mod 4) is down."""
+    chunk = 601
+    gidx = first_chunk * chunk + jnp.arange(chunk)
+    up = jnp.ones((1, chunk, 4), bool).at[:, :, 0].set(False)
+    assign, spill, unavail = simulator._routing_assign(
+        "round_robin", 4, KEY, first_chunk, gidx, 1, chunk, up=up)
+    counts = np.bincount(np.asarray(assign[0]), minlength=4)
+    assert counts[0] == 0
+    assert counts[1:].max() - counts[1:].min() <= 1
+    np.testing.assert_array_equal(np.asarray(assign[0]),
+                                  1 + np.asarray(gidx) % 3)
+    np.testing.assert_array_equal(np.asarray(spill[0]),
+                                  np.asarray(gidx) % 4 == 0)
+    assert not np.asarray(unavail).any()
 
 
 def test_all_replicas_down_counts_unavailable():
@@ -181,11 +207,92 @@ def test_plan_survive_faults_is_conservative():
     assert plan0.response_faulted_p95_ms is None
 
 
-def test_plan_rejects_double_injection():
-    with pytest.raises(ValueError, match="fault"):
+@pytest.mark.parametrize("fault", [
+    FaultSpec(mtbf_seconds=9.0),
+    FaultSpec(outages=((1, 0.0, 5.0),), broker_timeout_seconds=0.1)],
+    ids=["mtbf", "outages"])
+def test_plan_rejects_double_injection(fault):
+    """A spec that brings outages of its own would double-inject."""
+    with pytest.raises(ValueError, match="outages"):
         capacity.plan_capacity(
             PARAMS, 50.0, 0.3, survive_faults=1,
-            cluster=ClusterSpec(r=2, fault=FaultSpec(mtbf_seconds=9.0)))
+            cluster=ClusterSpec(fault=fault))
+
+
+TAIL = FaultSpec(broker_timeout_seconds=0.1, quorum_k=3,
+                 hedge_after_seconds=0.12)
+# server 0 of every replica 10% slower: the Eq 7 sizing cannot see it, so
+# the N+1 fleet misses the SLO and the loop grows it (10 -> 12 here)
+SLOW = FaultSpec(degraded=((0, 1.1),))
+
+
+def _plan(rate, fault, k, **kw):
+    return capacity.plan_capacity(
+        PARAMS, rate, 0.3, simulate=True, key=KEY, n_queries=4_000,
+        survive_faults=k, cluster=ClusterSpec(fault=fault), **kw)
+
+
+def test_plan_accepts_quorum_hedge_spec():
+    """A timeout, quorum and hedge run in both simulations; the faulted
+    run's partial-quorum share is on the plan."""
+    plan1 = _plan(120.0, TAIL, 1)
+    plan0 = _plan(120.0, TAIL, 0)
+    assert plan1.n_replicas >= plan0.n_replicas + 1
+    assert 0.0 < plan1.faulted_degraded_fraction < 1.0
+    assert plan1.response_faulted_p95_ms <= 300.0
+    assert plan0.faulted_degraded_fraction is None
+    assert plan0.response_faulted_p95_ms is None
+
+
+def test_plan_simulated_numbers_describe_returned_fleet(events):
+    """The loop grows the fleet past N+1, counting one faulted simulation
+    for each fleet it tries (10 and 11 miss, 12 holds), and both simulated
+    checks on the plan are runs of the fleet it returns: bit for bit a
+    direct run at ``n_replicas``, with and without replica 0 down for the
+    whole run, read as the planner reads one."""
+    n_base = _plan(100.0, None, 0).n_replicas
+    before = events["/repro/plan/faulted_sim"]
+    plan = _plan(100.0, SLOW, 1)
+    assert plan.n_replicas == n_base + 3
+    assert events["/repro/plan/faulted_sim"] - before == 3
+    whole = capacity._read(
+        run(SLOW, r=plan.n_replicas, rate=100.0, chunk_size=4_096))
+    down = capacity._read(
+        run(dataclasses.replace(SLOW, outages=((0, 0.0, math.inf),)),
+            r=plan.n_replicas, rate=100.0, chunk_size=4_096))
+    assert plan.response_simulated_ms == float(whole["mean"]) * 1e3
+    assert plan.response_simulated_p95_ms == float(whole["p95"]) * 1e3
+    assert plan.response_faulted_p95_ms == float(down["p95"]) * 1e3
+    assert plan.response_faulted_p95_ms <= 300.0
+    assert plan.faulted_degraded_fraction == 0.0     # no timeout: whole
+
+
+@pytest.mark.parametrize("need", [5, 6, 7, 12, 40, 100, 255, 256, 300])
+def test_fleet_growth_finds_the_smallest_in_log_steps(need):
+    """The N+k loop's search: from 5 replicas, the smallest fleet that
+    holds (or the cap of 256 where none below it does), trying
+    O(log n) fleets, each once."""
+    tried = []
+
+    def holds(n):
+        tried.append(n)
+        return n >= need
+
+    n = capacity._grow(holds, 5, 256)
+    assert n == min(need, 256)
+    assert len(tried) == len(set(tried))
+    assert len(tried) <= 2 * math.ceil(math.log2(256 - 5 + 1)) + 1
+
+
+def test_plan_warns_when_the_cap_stops_growth(monkeypatch):
+    """Where the faulted p95 cannot meet the SLO the loop stops at the
+    simulation cap and says so."""
+    n_base = _plan(100.0, None, 0).n_replicas
+    monkeypatch.setattr(capacity, "_SIM_REPLICA_CAP", n_base + 2)
+    with pytest.warns(UserWarning, match="cap"):
+        plan = _plan(100.0, FaultSpec(degraded=((0, 1.5),)), 1)
+    assert plan.n_replicas == n_base + 2
+    assert plan.response_faulted_p95_ms > 300.0
 
 
 def test_sweep_fault_axis_round_trips():

@@ -7,6 +7,7 @@ inside a fixture, so importing this file loads no TPU library.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import simulator
 from repro.core.cluster import ClusterSpec
+from repro.core.faults import FaultSpec
 from repro.core.queueing import ServerParams
 from repro.kernels.maxplus_scan import ops as mp_ops
 from repro.kernels.maxplus_scan.kernel import (maxplus_scan_pallas,
@@ -72,4 +74,27 @@ def test_stream_program_compiles_with_kernel(one_chip, monkeypatch, r):
     for stage in ("broker", "server"):
         assert f"%maxplus_scan_{stage}" in text
         assert f"stream.{stage}/" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_nk_tail_stream_program_compiles_with_kernel(one_chip, monkeypatch):
+    """The N+1 what-if's faulted run: replica 0 of 3 down for the whole run
+    (round-robin on the sorted path, so the segmented kernel), the
+    95-of-100 quorum's sort and the hedge draws."""
+    monkeypatch.setattr(mp_ops, "interpret_mode", lambda: False)
+    jax.clear_caches()
+    vec = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    fault = FaultSpec(outages=((0, 0.0, math.inf),),
+                      broker_timeout_seconds=0.15, quorum_k=95,
+                      hedge_after_seconds=0.21)
+    compiled = jax.jit(functools.partial(
+        simulator.simulate_fork_join_batch, n_queries=3 * 1024, p=100,
+        impl="pallas", chunk_size=1024,
+        cluster=ClusterSpec(r=3, fault=fault))).lower(
+            key, vec, ServerParams(*(vec,) * 6)).compile()
+    text = compiled.as_text()
+    for stage in ("broker", "server"):
+        assert f"%maxplus_segment_scan_{stage}" in text
+    assert "stream.fault/" in text and "stream.join/" in text
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
